@@ -22,11 +22,7 @@ ARCHS = ["qwen3-14b", "smollm-360m", "yi-9b", "moonshot-v1-16b-a3b"]
 
 
 def _abstract_production_mesh():
-    AM = jax.sharding.AbstractMesh
-    try:  # jax<=0.4.x: AbstractMesh(shape_tuple=((name, size), ...))
-        return AM((("data", 16), ("model", 16)))
-    except TypeError:  # jax>=0.5: AbstractMesh(axis_sizes, axis_names)
-        return AM((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def template_count(arch: str, n_buckets: int = 512, max_seq: int = 64):

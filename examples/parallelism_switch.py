@@ -10,8 +10,9 @@ reuses the archived template program byte-identically and stamps only
 rank-dependent communication state (peer tables, mesh coordinates,
 rank-relative buffer offsets) for the deployment mesh.
 
-This example runs on 8 placeholder devices: one offline SAVE on the
-single-device capture mesh, then the same engine *process* serves a (2,4)
+This example is a CPU tool and runs on 8 CPU placeholder devices (it pins
+itself to the CPU backend before JAX loads, so it never takes an
+accelerator): one offline SAVE on the single-device capture mesh, then the same engine *process* serves a (2,4)
 data x model mesh and hot-switches to a (4,2) mesh — both cold starts are
 rank-stamped LOADs of the one archive (``fallback_compiles == 0``), and
 in-flight requests keep their generated prefixes across the switch (the
@@ -19,7 +20,9 @@ thing process-level checkpoint/restore cannot do, §2.3).
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=8")))
 
 import time  # noqa: E402
 

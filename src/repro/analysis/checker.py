@@ -508,7 +508,6 @@ def check_ir(archive: Archive, loc: str,
     ``RankDelta``, else the stamped restore path would serve a program whose
     rank-dependent state was never patched."""
     import jax
-    import jax.export  # noqa: F401  (not re-exported on jax<=0.4.x)
     out: List[Finding] = []
     manifest = manifest if manifest is not None else archive.manifest
     cover = _covered_peer_rows(manifest)

@@ -32,8 +32,16 @@ PLACEHOLDER_FLAG = "--xla_force_host_platform_device_count"
 
 
 def placeholder_env(n_devices: int, extra_env: Optional[dict] = None) -> dict:
+    """Environment of a child process that sees ``n_devices`` CPU
+    placeholder devices. The child is pinned to the CPU backend: its parent
+    has usually imported JAX already and may hold the accelerator, which
+    belongs to one process at a time. The placeholder flag is appended to
+    any inherited ``XLA_FLAGS``."""
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"{PLACEHOLDER_FLAG}={n_devices}"
+    env["XLA_FLAGS"] = " ".join(
+        f for f in (env.get("XLA_FLAGS", ""), f"{PLACEHOLDER_FLAG}={n_devices}")
+        if f)
+    env["JAX_PLATFORMS"] = "cpu"
     env.update(extra_env or {})
     return env
 
@@ -47,10 +55,9 @@ def capture_devices_available(n: int) -> bool:
 def run_in_capture_process(script: str, n_devices: int, *,
                            timeout: float = 1200.0,
                            pythonpath: str = "src") -> subprocess.CompletedProcess:
-    """Run a python snippet in a fresh process with the capture topology.
-    (jax pins the device count at first init, so capture topology must be
-    established before any jax import — the same reason dryrun.py sets
-    XLA_FLAGS on its first two lines.)"""
+    """Run a python snippet in a fresh CPU process with the capture topology
+    (``placeholder_env``). JAX pins the device count at first init, so the
+    topology must be set before the child imports JAX."""
     env = placeholder_env(n_devices)
     env["PYTHONPATH"] = pythonpath + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.run([sys.executable, "-c", script], env=env,

@@ -31,7 +31,6 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
-import jax.export  # not re-exported by bare `import jax` on jax<=0.4.x
 
 from repro.core.archive import Archive
 from repro.core.memory_plan import MemoryPlan
@@ -83,21 +82,16 @@ def canonical_export_bytes(exp) -> bytes:
     accepts it unchanged (locations become "unknown").
 
     Uses private jax internals (the Exported dataclass layout and
-    ``_module_to_bytecode``); any drift falls back to the raw — still
-    loadable, just dedup-hostile — serialization.
+    ``_module_to_bytecode``) of the pinned JAX (``requirements.txt``).
     """
-    try:
-        from jax._src.export import _export
-        from jax._src.interpreters import mlir as _mlir
-        from jax._src.lib.mlir import ir as _ir
-        with _mlir.make_ir_context():
-            mod = _ir.Module.parse(exp.mlir_module())
-            text = mod.operation.get_asm(enable_debug_info=False)
-            ser = _export._module_to_bytecode(_ir.Module.parse(text))
-        exp = dataclasses.replace(exp, mlir_module_serialized=ser)
-    except Exception:
-        pass
-    return exp.serialize()
+    from jax._src.export import _export
+    from jax._src.interpreters import mlir as _mlir
+    from jax._src.lib.mlir import ir as _ir
+    with _mlir.make_ir_context():
+        mod = _ir.Module.parse(exp.mlir_module())
+        text = mod.operation.get_asm(enable_debug_info=False)
+        ser = _export._module_to_bytecode(_ir.Module.parse(text))
+    return dataclasses.replace(exp, mlir_module_serialized=ser).serialize()
 
 
 def foundry_save(specs: Sequence[CaptureSpec], mesh, *,

@@ -35,7 +35,6 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.collective_stub import (identity_device_count, mesh_identity,
                                         peer_groups, rank_coords)
@@ -111,69 +110,22 @@ class ReshardingExecutable:
     before calling it — the thing that lets an executable compiled under one
     mesh accept deployment-mesh-committed arrays under another.
 
-    Donated args (``donate_argnums``, recorded in the archive manifest at
-    SAVE) are materialized through ``jnp.copy`` so the wrapped executable
-    only ever donates buffers this wrapper owns. This mirrors the paper's
-    replay discipline (parameters are patched into graph-owned buffers,
-    cuGraphExecUpdate-style, never borrowed from the caller) and is also
-    load-bearing here: XLA-CPU (jax 0.4.x) crashes — heap corruption /
-    segfault, reproduced 200/200 trials without the copy — when a
-    *deserialized* executable donates a buffer produced by ``device_put`` or
-    aliased by the caller. Copies of XLA-computation outputs donate safely,
-    and non-donated args need no copy (verified 300 trials). When the donate
-    set is unknown (``donate_argnums=None``), every arg is copied.
-
-    Feedback fast path (device-resident decode): leaves of the wrapper's own
-    *previous* outputs are provably XLA-computation outputs with the exact
-    shardings this executable produces, so when the caller feeds them back
-    (cache' of step k donated into step k+1) they are passed through with no
-    copy and no device_put. Steady-state decode therefore donates the KV
-    cache truly in place; the copy only triggers for host-touched leaves
-    (fresh pools, ``device_put``-resharded rows, prefill-mutated leaves).
-    Ownership is tracked by identity of the last call's output leaves — the
-    engine holds those same objects until it passes them back, so the ids
-    cannot have been recycled.
+    Donated args are handed over as they arrive, exactly as a freshly jitted
+    step donates them: the caller gives up the buffer (the engine replaces
+    its KV cache with the step's output). An arg already on its recorded
+    sharding passes through ``device_put`` unchanged, so steady-state decode
+    donates the cache in place; a re-laid arg is donated as the new copy.
     """
 
     is_stamped = False
 
-    def __init__(self, executable: Any,
-                 donate_argnums: Optional[Sequence[int]] = None):
+    def __init__(self, executable: Any):
         self._exe = executable
-        self._donate = (None if donate_argnums is None
-                        else frozenset(int(i) for i in donate_argnums))
-        self._owned: Dict[int, Any] = {}  # id -> leaf of the last output
-        try:
-            self._in_shardings = executable.input_shardings[0]
-        except Exception:
-            self._in_shardings = None
-
-    def _owns(self, leaf) -> bool:
-        return self._owned.get(id(leaf)) is leaf
-
-    def _rebind(self, i, arg, sharding):
-        if not (self._donate is None or i in self._donate):
-            return jax.device_put(arg, sharding) if sharding is not None else arg
-        leaves, treedef = jax.tree.flatten(arg)
-        if all(map(self._owns, leaves)):
-            return arg  # pure feedback of our own output: donation-safe as-is
-        put = jax.device_put(arg, sharding) if sharding is not None else arg
-        out = [pl if (pl is ol and self._owns(ol)) else jnp.copy(pl)
-               for ol, pl in zip(leaves, jax.tree.leaves(put))]
-        return jax.tree.unflatten(treedef, out)
+        self._in_shardings = executable.input_shardings[0]
 
     def __call__(self, *args):
-        shardings = (self._in_shardings if self._in_shardings is not None
-                     else (None,) * len(args))
-        args = tuple(self._rebind(i, a, s)
-                     for i, (a, s) in enumerate(zip(args, shardings)))
-        out = self._exe(*args)
-        # Remember only the latest outputs: they are the only buffers the
-        # caller can legally feed back for donation (older ones were already
-        # donated away). Strong refs are free — the previous outputs are the
-        # current inputs, already consumed.
-        self._owned = {id(l): l for l in jax.tree.leaves(out)}
-        return out
+        return self._exe(*(jax.device_put(a, s)
+                           for a, s in zip(args, self._in_shardings)))
 
 
 class StampedExecutable(ReshardingExecutable):
@@ -190,9 +142,8 @@ class StampedExecutable(ReshardingExecutable):
     is_stamped = True
 
     def __init__(self, executable: Any, rank_deltas: Sequence[RankDelta],
-                 capture_identity: dict, deploy_identity: dict,
-                 donate_argnums: Optional[Sequence[int]] = None):
-        super().__init__(executable, donate_argnums)
+                 capture_identity: dict, deploy_identity: dict):
+        super().__init__(executable)
         self.rank_deltas = list(rank_deltas)
         self.capture_identity = dict(capture_identity)
         self.deploy_identity = dict(deploy_identity)
@@ -208,11 +159,8 @@ class StampedExecutable(ReshardingExecutable):
 
 
 def stamp_template(executable: Any, rank_deltas: Sequence[RankDelta],
-                   capture_identity: dict, mesh,
-                   donate_argnums: Optional[Sequence[int]] = None
-                   ) -> StampedExecutable:
+                   capture_identity: dict, mesh) -> StampedExecutable:
     """Stamp a deserialized template for the deployment ``mesh``."""
     return StampedExecutable(executable, rank_deltas, capture_identity,
                              mesh_identity(mesh) if mesh is not None
-                             else {"axes": [], "shape": []},
-                             donate_argnums)
+                             else {"axes": [], "shape": []})

@@ -62,11 +62,10 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
-import jax.export  # not re-exported by bare `import jax` on jax<=0.4.x
 
 from repro.core.archive import Archive
-from repro.core.collective_stub import (mesh_identity, same_topology,
-                                        stamp_compatible)
+from repro.core.collective_stub import (identity_device_count,
+                                        same_topology, stamp_compatible)
 from repro.core.memory_plan import MemoryPlan
 from repro.core.rank_stamp import (ReshardingExecutable, deployment_deltas,
                                    stamp_template)
@@ -170,13 +169,27 @@ class LoadReport:
                    if not k.startswith("background"))
 
 
-def _deserialize_template(blob: bytes):
+def _execution_devices(mesh, capture_identity: dict) -> list:
+    """The devices a deserialized template runs on: the capture-shaped
+    leading submesh of the deployment (the same ranks
+    ``_compile_from_export`` binds to), or the first device when LOAD is
+    un-meshed. ``deserialize_and_load`` would otherwise load the program onto
+    every device of the backend, and a one-device capture would expect one
+    shard per argument on each of them."""
+    devices = (list(mesh.devices.flat) if mesh is not None
+               else jax.devices())
+    return devices[:identity_device_count(capture_identity)]
+
+
+def _deserialize_template(blob: bytes, devices=None):
+    """Load a pickled ``serialize_executable`` payload onto ``devices``
+    (default: the first device, for an un-meshed LOAD)."""
     from jax.experimental import serialize_executable as se
     fault_point("archive.deserialize")
-    payload = pickle.loads(blob)
-    if isinstance(payload, tuple):
-        return se.deserialize_and_load(*payload)
-    return se.deserialize_and_load(payload)
+    serialized, in_tree, out_tree = pickle.loads(blob)
+    return se.deserialize_and_load(
+        serialized, in_tree, out_tree,
+        execution_devices=devices or jax.devices()[:1])
 
 
 def _template_cache(archive: Archive) -> dict:
@@ -225,9 +238,10 @@ class _TemplatePipeline:
     """
 
     def __init__(self, archive: Archive, jobs: Sequence[_TemplateJob],
-                 depth: int = 4):
+                 devices: Sequence, depth: int = 4):
         self.archive = archive
         self.jobs = list(jobs)
+        self.devices = list(devices)
         self.busy = {"fetch_s": 0.0, "deserialize_s": 0.0, "install_s": 0.0}
         self._fetched: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._ready: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
@@ -292,7 +306,8 @@ class _TemplatePipeline:
                 if job.error is None and job.deserialize and \
                         job.blob is not None:
                     try:
-                        job.exe = _deserialize_template(job.blob)
+                        job.exe = _deserialize_template(job.blob,
+                                                        self.devices)
                     except BaseException as e:
                         job.error, job.error_stage = e, "deserialize"
                 job.blob = None  # stage 2 owns the last ref to the bytes
@@ -444,8 +459,9 @@ def _foundry_load(archive: Archive, mesh, *, make_args, spec_names,
                 if b != g.template_bucket and b in g.bucket_export_blobs:
                     pending_exact.append((ps, g, b, donate))
         program_sets[name] = ps
-    pipe = _TemplatePipeline(archive, jobs,
-                             depth=max(1, pipeline_depth)).start()
+    pipe = _TemplatePipeline(
+        archive, jobs, _execution_devices(mesh, capture_identity),
+        depth=max(1, pipeline_depth)).start()
 
     try:
         # --- memory plan: preallocate + capture-window replay -------------
@@ -477,12 +493,11 @@ def _foundry_load(archive: Archive, mesh, *, make_args, spec_names,
                             and exe is not None
                             and g.executable_blob not in tcache):
                         tcache[g.executable_blob] = exe  # unwrapped: wrappers
-                        # below are per-LOAD (donation ownership per engine)
+                        # below are per-LOAD (rank deltas per deployment)
                     if exe is not None and rep.restore_path == "stamped":
                         try:
                             exe = stamp_template(exe, rank_deltas,
-                                                 capture_identity, mesh,
-                                                 job.donate)
+                                                 capture_identity, mesh)
                             rep.rank_stamped += len(rank_deltas)
                         except Exception as e:
                             job.error, job.error_stage = e, "stamp"
@@ -520,16 +535,13 @@ def _foundry_load(archive: Archive, mesh, *, make_args, spec_names,
                             archive,
                             g.bucket_export_blobs[g.template_bucket],
                             mesh, capture_identity,
-                            donate_argnums=job.donate), job.donate)
+                            donate_argnums=job.donate))
                     elif not isinstance(exe, ReshardingExecutable):
-                        # exact path: a DESERIALIZED template must never
-                        # donate a caller buffer produced by device_put
-                        # (XLA-CPU crash; rank_stamp.ReshardingExecutable
-                        # docstring). The wrapper copies host-touched donated
-                        # leaves once and passes its own fed-back outputs
-                        # through untouched, so the donated KV cache of
-                        # steady-state decode stays zero-copy.
-                        exe = ReshardingExecutable(exe, job.donate)
+                        # exact path: re-lay args onto the template's
+                        # recorded shardings (an un-meshed engine loading a
+                        # capture-mesh archive holds equivalent placements
+                        # under another description)
+                        exe = ReshardingExecutable(exe)
                     job.ps.set_template(g.key, exe)
                 rep.n_templates += 1
         rep.phases["templates_s"] = time.perf_counter() - t0
@@ -553,7 +565,7 @@ def _foundry_load(archive: Archive, mesh, *, make_args, spec_names,
                         mesh, capture_identity, donate_argnums=donate)
                     if rep.restore_path != "exact":
                         # exact exes must accept deployment-sharded args too
-                        exe = ReshardingExecutable(exe, donate)
+                        exe = ReshardingExecutable(exe)
                     ps.set_exact(b, exe)
                     rep.background_exact += 1
                 except Exception as e:
@@ -610,9 +622,7 @@ def _compile_from_export(archive: Archive, blob_hash: str, mesh,
     ``donate_argnums`` (the capture spec's, from the manifest) is re-applied
     so reconstructed executables keep the in-place buffer discipline of the
     capture — without it, the decode cache would be copied every step on any
-    bucket served by an exact realization. Fresh compiles donate
-    ``device_put``-produced buffers safely (the XLA-CPU crash is specific to
-    *deserialized* executables; rank_stamp.ReshardingExecutable docstring).
+    bucket served by an exact realization.
 
     A jax.export program is pinned to its capture-time device count. When the
     deployment mesh's count differs, the program is bound onto a
@@ -635,18 +645,11 @@ def _compile_from_export(archive: Archive, blob_hash: str, mesh,
         call_mesh = Mesh(np.asarray(devs).reshape(tuple(shape)),
                          tuple(capture_identity.get("axes") or ["devices"]))
     fn = jax.jit(exp.call, donate_argnums=tuple(donate_argnums or ()))
+    # the export's recorded HloShardings, rebound onto the deployment mesh
     flat = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)
-            for a, s in zip(exp.in_avals, _exp_shardings(exp, call_mesh))]
+            for a, s in zip(exp.in_avals, exp.in_shardings_jax(call_mesh))]
     args, kwargs = jax.tree.unflatten(exp.in_tree, flat)
     return fn.lower(*args, **kwargs).compile()
-
-
-def _exp_shardings(exp, mesh):
-    """Rebind the export's recorded HloShardings onto the deployment mesh."""
-    try:
-        return list(exp.in_shardings_jax(mesh))
-    except Exception:
-        return [None] * len(exp.in_avals)
 
 
 def wait_for_background(rep: LoadReport, timeout: float = 300.0,

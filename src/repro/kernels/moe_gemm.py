@@ -13,11 +13,14 @@ Grid: (E, C/bc, F/bf, D/bd).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, activation: str):
@@ -41,7 +44,7 @@ def _gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, activation: str):
 
 def moe_grouped_gemm_kernel(xe, w, *, activation: str = "none",
                             bc: int = 128, bf: int = 128, bd: int = 128,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """xe: [E, C, D]; w: [E, D, F] -> [E, C, F] (optionally silu-activated)."""
     E, C, D = xe.shape
     _, _, F = w.shape
@@ -59,5 +62,5 @@ def moe_grouped_gemm_kernel(xe, w, *, activation: str = "none",
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, c, f, d: (e, c, f)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), xe.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xe, w)

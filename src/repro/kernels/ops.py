@@ -23,25 +23,29 @@ from repro.kernels import moe_gemm as _mg
 from repro.kernels import ssm_scan as _ss
 from repro.kernels import ref as _ref
 
-INTERPRET = True  # CPU container: interpret mode; flip on real TPU.
-
 
 def _autotune(kernel_name: str, fn_for, candidates, probe_args) -> Dict[str, Any]:
     """Pick the fastest candidate options by timing small probes (the
-    first-use cost the catalog eliminates)."""
-    best, best_t = None, float("inf")
+    first-use cost the catalog eliminates). A candidate the compiler refuses
+    is skipped; when every candidate is refused, the last refusal raises."""
+    best, best_t, refused = None, float("inf"), None
     for opts in candidates:
         try:
-            f = jax.jit(functools.partial(fn_for, **opts))
-            f(*probe_args)  # compile
-            t0 = time.perf_counter()
-            jax.block_until_ready(f(*probe_args))
-            dt = time.perf_counter() - t0
-        except Exception:
+            f = jax.jit(functools.partial(fn_for, **opts)).lower(
+                *probe_args).compile()
+        except Exception as e:  # the compiler's refusal of this block shape
+            refused = e
             continue
+        jax.block_until_ready(f(*probe_args))  # warm-up
+        t0 = time.perf_counter()
+        jax.block_until_ready(f(*probe_args))
+        dt = time.perf_counter() - t0
         if dt < best_t:
             best, best_t = opts, dt
-    return best or candidates[0]
+    if best is None:
+        raise RuntimeError(f"{kernel_name}: no candidate of {candidates} "
+                           f"compiles") from refused
+    return best
 
 
 def _tuned_call(kernel_name: str, fn_for: Callable, candidates, args,
@@ -65,9 +69,9 @@ def _tuned_call(kernel_name: str, fn_for: Callable, candidates, args,
 def decode_attention(q, k_cache, v_cache, lengths, catalog=None):
     """Flash-decode. q: [B, H, Dh]; caches: [B, S, Hkv, Dh]; lengths: [B]."""
     S = k_cache.shape[1]
-    cands = [{"blk": b, "interpret": INTERPRET}
+    cands = [{"blk": b}
              for b in (256, 512, 1024) if S % b == 0 and b <= S]
-    cands = cands or [{"blk": S, "interpret": INTERPRET}]
+    cands = cands or [{"blk": S}]
     return _tuned_call("decode_attention", _da.decode_attention_kernel,
                        cands, (q, k_cache, v_cache, lengths), catalog)
 
@@ -75,11 +79,10 @@ def decode_attention(q, k_cache, v_cache, lengths, catalog=None):
 def mamba1_scan(dt, x, Bm, Cm, A, catalog=None):
     """Selective scan. dt/x: [B, T, C]; Bm/Cm: [B, T, N]; A: [C, N]."""
     T, C = x.shape[1], x.shape[2]
-    cands = [{"c_blk": cb, "t_chunk": tc, "interpret": INTERPRET}
+    cands = [{"c_blk": cb, "t_chunk": tc}
              for cb in (128, 256) for tc in (8, 16)
              if C % cb == 0 and T % tc == 0]
-    cands = cands or [{"c_blk": C, "t_chunk": min(8, T),
-                       "interpret": INTERPRET}]
+    cands = cands or [{"c_blk": C, "t_chunk": min(8, T)}]
     return _tuned_call("mamba1_scan", _ss.mamba1_scan_kernel, cands,
                        (dt, x, Bm, Cm, A), catalog)
 
@@ -88,11 +91,9 @@ def moe_grouped_gemm(xe, w, activation: str = "none", catalog=None):
     """Grouped expert GEMM. xe: [E, C, D]; w: [E, D, F]."""
     E, C, D = xe.shape
     F = w.shape[-1]
-    cands = [{"bc": bc, "bf": 128, "bd": 128, "activation": activation,
-              "interpret": INTERPRET}
+    cands = [{"bc": bc, "bf": 128, "bd": 128, "activation": activation}
              for bc in (64, 128)
              if C % bc == 0 and F % 128 == 0 and D % 128 == 0]
-    cands = cands or [{"bc": C, "bf": F, "bd": D, "activation": activation,
-                       "interpret": INTERPRET}]
+    cands = cands or [{"bc": C, "bf": F, "bd": D, "activation": activation}]
     return _tuned_call("moe_gemm", _mg.moe_grouped_gemm_kernel, cands,
                        (xe, w), catalog)

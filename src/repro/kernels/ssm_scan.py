@@ -3,7 +3,7 @@
 The recurrence h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t is sequential in t
 but embarrassingly parallel over channels: the TPU mapping blocks channels
 into VPU-width tiles kept in VMEM and walks time in chunks, carrying the
-state h [Cblk, N] in VMEM scratch across grid steps (grid iterates time
+state h [N, Cblk] in VMEM scratch across grid steps (grid iterates time
 innermost). This replaces the CUDA kernel's warp-parallel scan with a
 lane-parallel scan — no cross-lane communication is needed because B_t/C_t
 are shared across channels (broadcast along sublanes).
@@ -13,11 +13,14 @@ Grid: (B, C/Cblk, T/Tc); carry h in VMEM persists over the T dimension.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _scan_kernel(dt_ref, x_ref, B_ref, C_ref, A_ref, o_ref, h_ref,
@@ -28,26 +31,30 @@ def _scan_kernel(dt_ref, x_ref, B_ref, C_ref, A_ref, o_ref, h_ref,
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    A = A_ref[...]  # [Cblk, N]
-
-    def body(i, h):
-        dt_t = dt_ref[0, i, :].astype(jnp.float32)   # [Cblk]
-        x_t = x_ref[0, i, :].astype(jnp.float32)     # [Cblk]
-        B_t = B_ref[0, i, :].astype(jnp.float32)     # [N]
-        C_t = C_ref[0, i, :].astype(jnp.float32)     # [N]
-        decay = jnp.exp(dt_t[:, None] * A)           # [Cblk, N]
-        h = decay * h + (dt_t * x_t)[:, None] * B_t[None, :]
-        y_t = jnp.sum(h * C_t[None, :], axis=1)      # [Cblk]
-        o_ref[0, i, :] = y_t.astype(o_ref.dtype)
-        return h
-
-    h_ref[...] = jax.lax.fori_loop(0, t_chunk, body, h_ref[...])
+    # Whole-chunk loads, then static row slices: a dynamic index into the
+    # sublane dim of a block is refused by the TPU compiler.
+    dt = dt_ref[0].astype(jnp.float32)              # [Tc, Cblk]
+    dtx = dt * x_ref[0].astype(jnp.float32)         # [Tc, Cblk]
+    Bt = B_ref[0].astype(jnp.float32).T             # [N, Tc]
+    Ct = C_ref[0].astype(jnp.float32).T             # [N, Tc]
+    A = A_ref[...]                                  # [N, Cblk]
+    h = h_ref[...]                                  # [N, Cblk]
+    ys = []
+    for i in range(t_chunk):
+        decay = jnp.exp(dt[i:i + 1] * A)            # [N, Cblk]
+        h = decay * h + dtx[i:i + 1] * Bt[:, i:i + 1]
+        ys.append(jnp.sum(h * Ct[:, i:i + 1], axis=0, keepdims=True))
+    h_ref[...] = h
+    o_ref[0] = jnp.concatenate(ys, axis=0).astype(o_ref.dtype)
 
 
 def mamba1_scan_kernel(dt, x, Bm, Cm, A, *, c_blk: int = 128,
-                       t_chunk: int = 16, interpret: bool = True):
+                       t_chunk: int = 16, interpret: Optional[bool] = None):
     """dt, x: [B, T, C]; Bm, Cm: [B, T, N]; A: [C, N] (negative).
-    Returns y: [B, T, C] with y_t = C_t . h_t (caller adds D*x and gating)."""
+    Returns y: [B, T, C] with y_t = C_t . h_t (caller adds D*x and gating).
+
+    The state is kept channel-minor ([N, Cblk]: channels on the 128 lanes),
+    so A enters transposed."""
     B, T, C = x.shape
     N = Bm.shape[-1]
     c_blk = min(c_blk, C)
@@ -63,10 +70,10 @@ def mamba1_scan_kernel(dt, x, Bm, Cm, A, *, c_blk: int = 128,
             pl.BlockSpec((1, t_chunk, c_blk), lambda b, c, t: (b, t, c)),
             pl.BlockSpec((1, t_chunk, N), lambda b, c, t: (b, t, 0)),
             pl.BlockSpec((1, t_chunk, N), lambda b, c, t: (b, t, 0)),
-            pl.BlockSpec((c_blk, N), lambda b, c, t: (c, 0)),
+            pl.BlockSpec((N, c_blk), lambda b, c, t: (0, c)),
         ],
         out_specs=pl.BlockSpec((1, t_chunk, c_blk), lambda b, c, t: (b, t, c)),
         out_shape=jax.ShapeDtypeStruct((B, T, C), x.dtype),
-        scratch_shapes=[pltpu.VMEM((c_blk, N), jnp.float32)],
-        interpret=interpret,
-    )(dt, x, Bm, Cm, A)
+        scratch_shapes=[pltpu.VMEM((N, c_blk), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+    )(dt, x, Bm, Cm, A.T)

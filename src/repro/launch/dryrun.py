@@ -1,9 +1,10 @@
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh) cell.
 
-Proves the distribution config is coherent without real hardware: the SPMD
-program for the production mesh is traced, lowered and compiled on this host
-with placeholder devices (this single-host capture is also exactly Foundry's
-offline SAVE topology — see DESIGN.md §1).
+A CPU tool. It proves the distribution config is coherent without the
+hardware: the SPMD program for the production mesh is traced, lowered and
+compiled for 512 CPU placeholder devices (this single-host capture is also
+exactly Foundry's offline SAVE topology — see DESIGN.md §1). It pins itself
+to the CPU backend before JAX loads, so it never takes an accelerator.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch yi-9b --shape train_4k
@@ -15,7 +16,9 @@ EXPERIMENTS.md §Dry-run / §Roofline.
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 import argparse
 import json
 import time
@@ -81,8 +84,6 @@ def run_cell(arch: str, shape_name: str, mesh, *, verbose: bool = True,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     rec.update({
         "status": "ok",
         "lower_s": round(t_lower, 2),

@@ -21,17 +21,23 @@ from typing import Optional, Sequence, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """Every multi-device mesh of the repo is built here. Axes are ``Auto``:
+    the model code places arrays through sharding constraints and lets the
+    compiler propagate the rest (``jax.make_mesh`` defaults to ``Explicit``
+    axes, under which e.g. a gather from a vocab-sharded table must name its
+    output sharding)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
@@ -50,7 +56,7 @@ def make_capture_mesh() -> Mesh:
 def make_tp_mesh(n_model: int, n_data: int = 1) -> Mesh:
     """Tensor-parallel deployment mesh: (n_data, n_model) over
     ("data", "model")."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def mesh_device_count(mesh: Optional[Mesh]) -> int:
@@ -73,7 +79,7 @@ class MeshSpec:
     def build(self) -> Optional[Mesh]:
         if not self.shape:
             return None
-        return jax.make_mesh(tuple(self.shape), tuple(self.axes[:len(self.shape)]))
+        return make_mesh(self.shape, self.axes[:len(self.shape)])
 
     def describe(self) -> str:
         if not self.shape:

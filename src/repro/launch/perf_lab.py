@@ -5,10 +5,14 @@ configuration and print the roofline terms.
       --shape decode_32k --flags mixed_precision_attn=1
 
 Each EXPERIMENTS.md §Perf iteration is one baseline/flagged pair of runs.
+A CPU tool: it compiles for 512 CPU placeholder devices and pins itself to
+the CPU backend before JAX loads, so it never takes an accelerator.
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 import argparse
 import json
 

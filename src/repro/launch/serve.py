@@ -8,6 +8,12 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b-reduced \
         --load /tmp/qwen.fndry --requests 16
 
+    # the same at smollm-360m's published widths, on the accelerator
+    PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
+        --save bench_out/s.fndry
+    PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
+        --load bench_out/s.fndry
+
     # autoscaling fleet replaying a load spike against one shared archive
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b-reduced \
         --load /tmp/qwen.fndry --fleet --max-replicas 4 \
@@ -30,8 +36,10 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import random
 import time
+from pathlib import Path
 
 import jax
 
@@ -46,8 +54,25 @@ from repro.serving.fleet import AutoscalePolicy, Fleet, spike_trace
 from repro.serving.router import ModelPolicy, ModelRouter
 
 
+#: The checkout this module runs from (``src/repro/launch/serve.py``).
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def configure_compile_cache() -> tuple[str, bool]:
+    """Place JAX's persistent compilation cache; returns ``(dir,
+    was_empty)``. A set ``JAX_COMPILATION_CACHE_DIR`` is read by JAX itself
+    and used as is. Otherwise the cache is ``<checkout>/.jax_cache``: one
+    fixed path, so every later run from this checkout finds it again."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path, not (os.path.isdir(path) and os.listdir(path))
+
+
 def build(arch: str, max_batch: int, max_seq: int,
-          mesh=None) -> ServingEngine:
+          mesh=None, seed: int = 0) -> ServingEngine:
+    """An engine for ``arch`` with random weights from ``PRNGKey(seed)``."""
     cfg = get_arch(arch)
     if mesh is None:
         model = Model(cfg)
@@ -56,7 +81,7 @@ def build(arch: str, max_batch: int, max_seq: int,
         model = Model(cfg, ShardCtx(mesh=resolve_mesh(mesh)))
     eng = ServingEngine(model, max_batch=max_batch, max_seq=max_seq,
                         bucket_mode="pow2")
-    eng.load_weights(rng=jax.random.PRNGKey(0))
+    eng.load_weights(rng=jax.random.PRNGKey(seed))
     return eng
 
 
@@ -327,6 +352,7 @@ def main():
                          "(open in ui.perfetto.dev or chrome://tracing)")
     args = ap.parse_args()
 
+    configure_compile_cache()
     _obs_setup(args)
     try:
         _run(args, ap)

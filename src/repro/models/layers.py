@@ -340,13 +340,8 @@ def decode_attention_seqpar(q, k_cache, v_cache, k_new, v_new, lengths, *,
                 P(bspec, None, None), P(bspec))
     out_specs = (P(bspec, None, None, None), cspec(bspec, sspec),
                  cspec(bspec, sspec))
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        mapped = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-    else:  # jax 0.4.x spelling (check_rep is check_vma's predecessor)
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     out, kc, vc = mapped(q, k_cache, v_cache, k_new, v_new, lengths)
     return out, kc, vc
 

@@ -19,6 +19,7 @@ cannot fork per layout:
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, List, Optional
 
@@ -26,17 +27,29 @@ import jax
 import jax.numpy as jnp
 
 
+def _fits(sharding, shape) -> bool:
+    """Whether a ``NamedSharding`` applies to an array of ``shape``: the
+    spec names no more dims than it has, and every dim splits evenly over
+    the mesh axes the spec assigns to it."""
+    if len(sharding.spec) > len(shape):
+        return False
+    mesh_shape = sharding.mesh.shape
+    for size, axes in zip(shape, sharding.spec):
+        names = (axes,) if isinstance(axes, str) else (axes or ())
+        if size % math.prod(mesh_shape[a] for a in names):
+            return False
+    return True
+
+
 def reshard_rows(rows, sd, mesh):
     """Commit migrated rows to a destination pool's devices: the leaf's spec
-    sharding when it accepts the row-count (batch may not divide the data
-    axes), replicated on the mesh otherwise, first local device when
-    un-meshed (eager update ops reject operands committed to a different
-    mesh's device set). Shared by both pool layouts (slot and paged)."""
-    if sd.sharding is not None:
-        try:
-            return jax.device_put(rows, sd.sharding)
-        except Exception:
-            pass
+    sharding when it fits the rows (a paged pool's spec has a block dim the
+    rows lack, and the row count may not divide the data axes), replicated
+    on the mesh otherwise, first local device when un-meshed (eager update
+    ops reject operands committed to a different mesh's device set). Shared
+    by both pool layouts (slot and paged)."""
+    if sd.sharding is not None and _fits(sd.sharding, rows.shape):
+        return jax.device_put(rows, sd.sharding)
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec
         return jax.device_put(rows, NamedSharding(mesh, PartitionSpec()))

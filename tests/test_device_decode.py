@@ -1,5 +1,6 @@
 """Device-resident decode loop: token identity vs the pre-fusion host loop,
-O(B) transfer regression, donation feedback fast path, and lookup memoization.
+O(B) transfer regression, in-place donation through the dispatch wrapper,
+and lookup memoization.
 
 The device loop (serving/engine.py docstring) keeps decode state on the
 device end to end: the captured step fuses greedy sampling and donates the
@@ -183,28 +184,28 @@ def test_host_loop_transfer_is_O_batch_times_vocab(monkeypatch):
 # donation feedback fast path (ReshardingExecutable extension)
 # ---------------------------------------------------------------------------
 def test_resharding_executable_feedback_donation():
-    """Caller buffers are copied before donation (the XLA-CPU deserialized-
-    donation crash workaround), but the wrapper's own fed-back outputs are
-    donated in place — the steady-state decode contract."""
+    """The wrapper donates what it is handed, as a freshly jitted step does:
+    a caller buffer already on the recorded sharding and the wrapper's own
+    fed-back outputs are both donated in place — the steady-state decode
+    contract (the engine replaces its cache with each step's output)."""
     def f(cache, x):
         return {"v": cache["v"] + x}, cache["v"].sum()
 
     compiled = jax.jit(f, donate_argnums=(0,)).lower(
         {"v": jax.ShapeDtypeStruct((8,), jnp.float32)},
         jax.ShapeDtypeStruct((), jnp.float32)).compile()
-    wrap = ReshardingExecutable(compiled, donate_argnums=(0,))
+    wrap = ReshardingExecutable(compiled)
 
     c0 = {"v": jax.device_put(np.ones(8, np.float32))}  # host-origin buffer
     out1, _ = wrap(c0, jnp.float32(1.0))
-    assert not c0["v"].is_deleted(), \
-        "host-origin donated arg must be copied, not donated"
+    assert c0["v"].is_deleted(), "donated caller buffer is handed over"
     out2, _ = wrap(out1, jnp.float32(1.0))
     assert out1["v"].is_deleted(), \
         "fed-back wrapper output should be donated in place (no copy)"
     assert not out2["v"].is_deleted()
     np.testing.assert_allclose(np.asarray(out2["v"]), 3.0)
 
-    # a host-mutated leaf inside an otherwise-owned tree is re-materialized
+    # a host-rebuilt leaf is accepted like any other
     out3, _ = wrap({"v": jax.device_put(np.asarray(out2["v"]))},
                    jnp.float32(1.0))
     np.testing.assert_allclose(np.asarray(out3["v"]), 4.0)

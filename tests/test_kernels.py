@@ -1,5 +1,6 @@
-"""Per-kernel validation: shape/dtype sweeps in interpret mode vs the pure-jnp
-oracles in repro.kernels.ref, plus kernel-catalog behaviour."""
+"""Per-kernel validation: shape/dtype sweeps vs the pure-jnp oracles in
+repro.kernels.ref (interpret mode on a CPU backend, compiled on a TPU), plus
+kernel-catalog behaviour."""
 import functools
 
 import jax
@@ -37,8 +38,7 @@ class TestDecodeAttention:
         kc = jax.random.normal(ks[1], (B, S, Hkv, Dh), dtype)
         vc = jax.random.normal(ks[2], (B, S, Hkv, Dh), dtype)
         lengths = jax.random.randint(ks[3], (B,), 1, S - 1)
-        out = decode_attention_kernel(q, kc, vc, lengths, blk=blk,
-                                      interpret=True)
+        out = decode_attention_kernel(q, kc, vc, lengths, blk=blk)
         want = ref.decode_attention_ref(q, kc, vc, lengths)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -89,8 +89,7 @@ class TestPagedDecodeAttention:
         q = jax.random.normal(ks[0], (B, H, Dh), dtype)
         kp, vp, tables = self._rand_pool(ks[1], B, MB, bs, Hkv, Dh, dtype)
         lengths = jax.random.randint(ks[2], (B,), 1, MB * bs - 1)
-        out = decode_attention_paged_kernel(q, kp, vp, tables, lengths,
-                                            interpret=True)
+        out = decode_attention_paged_kernel(q, kp, vp, tables, lengths)
         want = ref.decode_attention_paged_ref(q, kp, vp, tables, lengths)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -163,8 +162,7 @@ class TestMamba1Scan:
         Cm = jax.random.normal(ks[3], (B, T, N), dtype)
         A = -jnp.exp(jax.random.normal(jax.random.PRNGKey(5), (C, N))) \
             .astype(jnp.float32)
-        out = mamba1_scan_kernel(dt, x, Bm, Cm, A, c_blk=cb, t_chunk=tc,
-                                 interpret=True)
+        out = mamba1_scan_kernel(dt, x, Bm, Cm, A, c_blk=cb, t_chunk=tc)
         want = ref.mamba1_scan_ref(dt, x, Bm, Cm, A)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -198,7 +196,7 @@ class TestMoeGemm:
         ks = jax.random.split(jax.random.PRNGKey(4), 2)
         xe = (jax.random.normal(ks[0], (E, C, D)) / np.sqrt(D)).astype(dtype)
         w = (jax.random.normal(ks[1], (E, D, F)) / np.sqrt(D)).astype(dtype)
-        out = moe_grouped_gemm_kernel(xe, w, activation=act, interpret=True)
+        out = moe_grouped_gemm_kernel(xe, w, activation=act)
         want = ref.moe_grouped_gemm_ref(xe, w, activation=act)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -219,6 +217,16 @@ class TestKernelCatalog:
         o2 = ops.decode_attention(q, kc, vc, lengths, catalog=cat)
         assert cat.stats["autotune_skipped"] == 1
         np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
+
+    def test_autotune_raises_when_no_candidate_compiles(self):
+        """No silent ``candidates[0]``: every candidate refused -> raise."""
+        q = jnp.zeros((1, 4, 64), jnp.float32)
+        kc = jnp.zeros((1, 256, 2, 64), jnp.float32)
+        lengths = jnp.asarray([10])
+        with pytest.raises(RuntimeError, match="no candidate"):
+            ops._autotune("decode_attention", decode_attention_kernel,
+                          [{"blk": 96}, {"blk": 160}],  # neither divides S
+                          (q, kc, kc, lengths))
 
     def test_catalog_roundtrip_through_archive(self):
         from repro.core.archive import Archive

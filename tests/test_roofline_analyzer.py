@@ -95,10 +95,7 @@ class TestLiveModule:
         x = jnp.zeros((8, D), jnp.float32)
         compiled = jax.jit(f).lower(ws, x).compile()
         cm = HloCostModel(compiled.as_text())
-        raw = compiled.cost_analysis()
-        if isinstance(raw, (list, tuple)):  # jax<=0.4.x returns [dict]
-            raw = raw[0]
-        raw = raw["flops"]
+        raw = compiled.cost_analysis()["flops"]
         ours = cm.dot_flops()
         per_layer = 2 * 8 * D * D
         # our count must cover all L layers (within the f32 penalty factor)
