@@ -43,6 +43,7 @@ from typing import Any, Dict, Iterator, Optional
 import msgpack
 
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import span
 from repro.serving.faults import fault_point
 
 try:
@@ -403,48 +404,49 @@ class Archive:
         equivalent) depot, or opening fails. The returned Archive's blob
         store IS the depot store, so blobs shared across models are fetched
         at most once depot-wide."""
-        # archive open is the first IO of every cold start: transient
-        # failures (archive still replicating onto this host) retry with
-        # bounded backoff before the replica is declared FAILED
-        f = io_retries(lambda: open(path, "rb"),  # noqa: SIM115
-                       f"archive {path}")
-        with f:
-            magic = f.read(len(MAGIC2))
-            if magic == MAGIC2:
-                (hlen,) = struct.unpack("<Q", f.read(8))
-                head = msgpack.unpackb(_decompress(f.read(hlen)),
-                                       raw=False, strict_map_key=False)
-                base = len(MAGIC2) + 8 + hlen
-                if head.get("depot"):
-                    if depot is None:
-                        raise ValueError(
-                            f"{path} is a depot-backed (thin) archive; "
-                            f"reopen it with Archive.load(path, depot=...)")
-                    missing = [h for h in head["index"]
-                               if not depot.has_blob(h)]
-                    if missing:
-                        # fail at open with the real cause, not with a
-                        # FileNotFoundError from some later blob fetch
-                        raise ValueError(
-                            f"{path} references {len(missing)} blob(s) the "
-                            f"depot at {depot.root} does not hold (first: "
-                            f"{missing[0]}); wrong depot?")
-                    for h, entry in head["index"].items():
-                        depot.store.register(h, entry)
-                    ar = cls(manifest=head["manifest"], blobs=depot.store)
+        with span("archive.open", cat="load"):
+            # archive open is the first IO of every cold start: transient
+            # failures (archive still replicating onto this host) retry with
+            # bounded backoff before the replica is declared FAILED
+            f = io_retries(lambda: open(path, "rb"),  # noqa: SIM115
+                           f"archive {path}")
+            with f:
+                magic = f.read(len(MAGIC2))
+                if magic == MAGIC2:
+                    (hlen,) = struct.unpack("<Q", f.read(8))
+                    head = msgpack.unpackb(_decompress(f.read(hlen)),
+                                           raw=False, strict_map_key=False)
+                    base = len(MAGIC2) + 8 + hlen
+                    if head.get("depot"):
+                        if depot is None:
+                            raise ValueError(
+                                f"{path} is a depot-backed (thin) archive; "
+                                f"reopen it with Archive.load(path, depot=...)")
+                        missing = [h for h in head["index"]
+                                   if not depot.has_blob(h)]
+                        if missing:
+                            # fail at open with the real cause, not with a
+                            # FileNotFoundError from some later blob fetch
+                            raise ValueError(
+                                f"{path} references {len(missing)} blob(s) the "
+                                f"depot at {depot.root} does not hold (first: "
+                                f"{missing[0]}); wrong depot?")
+                        for h, entry in head["index"].items():
+                            depot.store.register(h, entry)
+                        ar = cls(manifest=head["manifest"], blobs=depot.store)
+                        if not lazy:
+                            for h in head["index"]:
+                                ar.blobs[h]
+                        return ar
+                    ar = cls(manifest=head["manifest"],
+                             blobs=BlobStore(index=head["index"],
+                                             source=_FileSource(path, base)))
                     if not lazy:
-                        for h in head["index"]:
+                        for h in ar.blobs:  # fetch + verify everything up front
                             ar.blobs[h]
                     return ar
-                ar = cls(manifest=head["manifest"],
-                         blobs=BlobStore(index=head["index"],
-                                         source=_FileSource(path, base)))
-                if not lazy:
-                    for h in ar.blobs:  # fetch + verify everything up front
-                        ar.blobs[h]
-                return ar
-            f.seek(0)
-            return cls.from_bytes(f.read(), lazy=lazy)
+                f.seek(0)
+                return cls.from_bytes(f.read(), lazy=lazy)
 
     # -- debugging / storage accounting --------------------------------
     def blob_bytes(self) -> int:

@@ -6,7 +6,9 @@ Three small, dependency-free pieces:
   gauges, and histograms with Prometheus text exposition and a
   one-global-read disabled path (off by default).
 - :mod:`repro.obs.trace` — structured spans with thread attribution,
-  exported as Chrome/Perfetto trace-event JSON (off by default).
+  exported as Chrome/Perfetto trace-event JSON (off by default) and, while
+  a JAX profiler session collects, as profiler events on the clock of the
+  device ops.
 - :func:`configure_logging` — one-call console logging for the
   ``repro.*`` logger namespace used across the package.
 

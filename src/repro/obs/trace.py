@@ -15,13 +15,24 @@ its duration (callers such as ``restore.foundry_load`` reuse
 ``span.seconds`` to fill the legacy report dataclasses — one measurement,
 two consumers) but only records an event when tracing is on.
 
+A span has a second sink: while a JAX profiler session collects
+(``jax.profiler.trace``, ``start_trace`` or a ``start_server`` capture),
+it also opens a ``jax.profiler.TraceAnnotation`` of the same name, with its
+args as metadata.  The profiler stamps it on the clock of the device ops
+in the same ``.xplane.pb``, so TensorBoard or Perfetto show the program's
+spans above the device timeline and a device idle gap can be put down to
+the code the host was running.  With no session collecting, the sink
+costs one ``is_enabled()`` call per span.  :func:`complete` and
+:func:`instant` go to the JSON collector only.
+
 Event vocabulary used here (a small, valid subset of the format):
 
 - ``"X"`` complete events — spans with ``ts``/``dur`` in microseconds
 - ``"i"`` instant events — crashes, cutovers, shed decisions
 - ``"M"`` metadata events — ``thread_name`` / ``process_name``
 
-Stdlib only; must not import from the rest of ``repro``.
+Stdlib only, apart from ``jax.profiler``, imported on the first span;
+must not import from the rest of ``repro``.
 """
 from __future__ import annotations
 
@@ -53,6 +64,19 @@ _TRACING = False
 MAX_EVENTS = 500_000
 
 _VALID_PHASES = {"X", "B", "E", "i", "I", "M", "C"}
+
+# jax.profiler.TraceAnnotation, imported by the first span's _collecting()
+_Annotation: Any = None
+
+
+def _collecting() -> bool:
+    """Whether a profiler session collects. The first call imports
+    ``jax.profiler`` and rebinds this name to ``is_enabled`` itself."""
+    global _Annotation, _collecting
+    from jax.profiler import TraceAnnotation
+    _Annotation = TraceAnnotation
+    _collecting = TraceAnnotation.is_enabled
+    return _collecting()
 
 
 class TraceCollector:
@@ -207,9 +231,12 @@ class span:
         with span("load.parse", cat="load") as sp:
             manifest = archive.manifest
         rep.phases["parse_s"] = sp.seconds
+
+    While a profiler session collects, the block is also a
+    ``TraceAnnotation`` of the same name (module docstring).
     """
 
-    __slots__ = ("name", "cat", "args", "seconds", "_t0")
+    __slots__ = ("name", "cat", "args", "seconds", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str = "", **args: Any):
         self.name = name
@@ -217,20 +244,35 @@ class span:
         self.args = args or None
         self.seconds = 0.0
         self._t0 = 0.0
+        self._ann = None
+
+    def set(self, **args: Any) -> None:
+        """Add args known only partway through the block; call at most
+        once per key (the profiler keeps every value it is given)."""
+        if self.args is None:
+            self.args = args
+        else:
+            self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __enter__(self) -> "span":
+        if _collecting():
+            self._ann = _Annotation(self.name, **(self.args or {}))
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.seconds = time.perf_counter() - self._t0
+        if exc_type is not None:
+            self.set(error=exc_type.__name__)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if _TRACING:
-            args = self.args
-            if exc_type is not None:
-                args = dict(args or {})
-                args["error"] = exc_type.__name__
             _COLLECTOR.add_complete(self.name, self.cat, self._t0,
-                                    self.seconds, args)
+                                    self.seconds, self.args)
         return False
 
 

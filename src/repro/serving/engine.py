@@ -48,7 +48,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 from repro.obs.trace import span
 
 from repro.core import (Archive, CaptureSpec, MemoryPlan, ProgramSet,
@@ -272,25 +271,27 @@ class ServingEngine:
     def load_weights(self, params=None, rng=None):
         """Weight loading is assumed solved (RDMA, 1-2 s; paper §2); here we
         either take provided params or init. Registers with the memory plan."""
-        t0 = time.perf_counter()
-        self.params = params if params is not None else self.model.init(
-            rng if rng is not None else jax.random.PRNGKey(0))
-        for path, leaf in jax.tree_util.tree_flatten_with_path(self.params)[0]:
-            self.memory_plan.alloc(
-                "params" + jax.tree_util.keystr(path),
-                leaf.size * leaf.dtype.itemsize)
-        return time.perf_counter() - t0
+        with span("engine.load_weights", cat="engine") as sp:
+            self.params = params if params is not None else self.model.init(
+                rng if rng is not None else jax.random.PRNGKey(0))
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                    self.params)[0]:
+                self.memory_plan.alloc(
+                    "params" + jax.tree_util.keystr(path),
+                    leaf.size * leaf.dtype.itemsize)
+        return sp.seconds
 
     def _init_pool(self):
-        if self.kv_layout == "paged":
-            self.pool = PagedKVCachePool(
-                self.model, self.max_batch, self.max_seq,
-                bucket_of=self._bucket_of, memory_plan=self.memory_plan,
-                block_size=self.kv_block_size, n_blocks=self.kv_blocks)
-        else:
-            self.pool = KVCachePool(
-                self.model, self.max_batch, self.max_seq,
-                bucket_of=self._bucket_of, memory_plan=self.memory_plan)
+        with span("engine.init_pool", cat="engine"):
+            if self.kv_layout == "paged":
+                self.pool = PagedKVCachePool(
+                    self.model, self.max_batch, self.max_seq,
+                    bucket_of=self._bucket_of, memory_plan=self.memory_plan,
+                    block_size=self.kv_block_size, n_blocks=self.kv_blocks)
+            else:
+                self.pool = KVCachePool(
+                    self.model, self.max_batch, self.max_seq,
+                    bucket_of=self._bucket_of, memory_plan=self.memory_plan)
         self._fill_target.clear()
         self._tokens_dev = None
         self._tokens_dirty = True
@@ -520,38 +521,24 @@ class ServingEngine:
             return self._put_tokens(t)
         return self._rebuild_tokens(exec_bucket, by_slot)
 
-    def _step_device(self, bucket: int, by_slot) -> np.ndarray:
-        """Fused dispatch: donated cache, on-device sampling, O(B) readback."""
+    def _program(self, bucket: int):
+        """The bucket the step executes at and its program."""
         if self._eager_mode:
-            exec_bucket, exe = bucket, self._eager_jit
-        else:
-            exec_bucket, exe, _path = self.programs.lookup(bucket)
-            if exec_bucket != bucket:
-                self.pool._resize(exec_bucket)
-        toks = self._device_tokens(exec_bucket, by_slot)
-        cache, sampled = exe(self.params, self.pool.cache, toks)
-        self.pool.cache = cache
-        self._tokens_dev = sampled
-        self._tokens_bucket = exec_bucket
-        self._tokens_dirty = False
-        ids = np.asarray(sampled)  # the loop's only device->host readback
-        self.transfer_stats["d2h_bytes"] += ids.nbytes
-        return ids
+            return bucket, self._eager_jit
+        exec_bucket, exe, _path = self.programs.lookup(bucket)
+        return exec_bucket, exe
 
-    def _step_host(self, bucket: int, by_slot) -> np.ndarray:
-        """Pre-fusion loop (decode_loop="host"): host re-packs tokens every
-        step and pulls the full padded-vocab logits back to argmax in numpy.
-        Kept as the measurable baseline for fig9 and the identity tests."""
-        if self._eager_mode:
-            exec_bucket, exe = bucket, self._eager_jit
-        else:
-            exec_bucket, exe, _path = self.programs.lookup(bucket)
-            if exec_bucket != bucket:
-                self.pool._resize(exec_bucket)
-        cache, logits = exe(self.params, self.pool.cache,
-                            self._rebuild_tokens(exec_bucket, by_slot))
-        self.pool.cache = cache
-        logits_np = np.asarray(logits[:, :self.cfg.vocab_size])
+    def _read_ids(self, out) -> np.ndarray:
+        """Block on the step's output and bring the sampled ids to the host:
+        the fused step's O(B) ids (the device loop's only device->host
+        readback), or the pre-fusion loop's full padded-vocab logits,
+        argmaxed in numpy (kept as the measurable baseline for fig9 and the
+        identity tests)."""
+        if self.decode_loop == "device":
+            ids = np.asarray(out)
+            self.transfer_stats["d2h_bytes"] += ids.nbytes
+            return ids
+        logits_np = np.asarray(out[:, :self.cfg.vocab_size])
         self.transfer_stats["d2h_bytes"] += logits_np.nbytes
         return logits_np.argmax(axis=-1)
 
@@ -644,46 +631,56 @@ class ServingEngine:
         """One engine iteration: admit + decode one token for all running.
         Returns number of active requests served.
 
-        When telemetry is on (obs.metrics enabled and/or tracing active)
-        the step is timed once and the measurement feeds both the
-        ``serving_tpot_seconds`` histogram and an ``engine.decode_step``
-        trace span; when off, the cost is two module-global reads."""
-        if not (obs_metrics.enabled() or obs_trace.active()):
-            return self._step_impl()
-        t0 = time.perf_counter()
-        n = self._step_impl()
-        dt = time.perf_counter() - t0
-        if n:  # idle ticks are not decode steps — they would skew TPOT
-            if obs_metrics.enabled():
-                _M_TPOT.observe(dt)
-                _M_DECODE_STEPS.inc()
-            if obs_trace.active():
-                obs_trace.collector().add_complete(
-                    "engine.decode_step", "engine", t0, dt, {"batch": n})
-        return n
-
-    def _step_impl(self) -> int:
+        A step that has work is one ``engine.step`` span (args ``rows``,
+        ``bucket``, ``fill_rows``) over six children in the order they run:
+        ``engine.admit``, ``engine.pool_sync``, ``engine.tokens``,
+        ``engine.dispatch`` (an asynchronous enqueue), ``engine.readback``
+        (the wait for the device) and ``engine.finish``. The span's
+        ``seconds`` feed ``serving_tpot_seconds`` and
+        ``engine_decode_steps_total``. A tick with nothing queued or
+        running records no span; one that admits nothing from its queue
+        records an ``engine.step`` with ``rows=0`` and only its
+        ``engine.admit``, which feeds neither metric."""
         # injected BEFORE any scheduler/pool mutation: a crash here leaves
         # the engine coherent, so the fleet's salvage path (export_inflight)
         # can migrate the in-flight KV rows instead of re-prefilling
         fault_point("engine.decode_step", tag=self.fault_tag)
+        if not self.scheduler.queue and self.pool.n_active == 0:
+            return 0
+        with span("engine.step", cat="engine") as sp:
+            n = self._step(sp)
+        if n:  # ticks that admitted nothing would skew TPOT
+            _M_TPOT.observe(sp.seconds)
+            _M_DECODE_STEPS.inc()
+        return n
+
+    def _step(self, sp: span) -> int:
         sched, pool = self.scheduler, self.pool
-        self._admit(self.max_batch - pool.n_active)
-        if self.kv_layout == "paged":
-            self._preempt_until_feasible()
+        paged = self.kv_layout == "paged"
+        with span("engine.admit", cat="engine"):
+            self._admit(self.max_batch - pool.n_active)
+            if paged:
+                self._preempt_until_feasible()
         n = pool.n_active
         if n == 0:
+            sp.set(rows=0)
             return 0
-        if self.kv_layout == "paged":
-            # rebuild the (small) device block tables if scheduling dirtied
-            # them; steady-state decode takes the free fast path
-            self.transfer_stats["h2d_bytes"] += pool.sync()
-            if self._fill_target:
-                # fill steps feed prompt tokens, not the sampled ids
-                self._tokens_dirty = True
         bucket = pool.cur_bucket
+        with span("engine.pool_sync", cat="engine"):
+            if paged:
+                # rebuild the (small) device block tables if scheduling
+                # dirtied them; steady-state decode takes the free fast path
+                self.transfer_stats["h2d_bytes"] += pool.sync()
+                if self._fill_target:
+                    # fill steps feed prompt tokens, not the sampled ids
+                    self._tokens_dirty = True
+            # the lookup names the bucket the pool must be resized to
+            exec_bucket, exe = self._program(bucket)
+            if exec_bucket != bucket:
+                pool._resize(exec_bucket)
+        sp.set(rows=n, bucket=exec_bucket, fill_rows=len(self._fill_target))
         by_slot = {r.slot: r for r in sched.running.values()}
-        if self.kv_layout == "paged":
+        if paged:
             # recordability is decided on PRE-step lengths: the step feeding
             # the last prompt token produces the first real sample
             eligible = {
@@ -691,26 +688,38 @@ class ServingEngine:
                        or pool.host_len[slot]
                        >= self._fill_target[req.req_id] - 1)
                 for slot, req in by_slot.items()}
+        with span("engine.tokens", cat="engine"):
+            if self.decode_loop == "device":
+                toks = self._device_tokens(exec_bucket, by_slot)
+            else:
+                # the pre-fusion loop re-packs the tokens every step
+                toks = self._rebuild_tokens(exec_bucket, by_slot)
+        with span("engine.dispatch", cat="engine"):
+            # donated cache; the fused program samples on the device
+            pool.cache, out = exe(self.params, pool.cache, toks)
         if self.decode_loop == "device":
-            next_tokens = self._step_device(bucket, by_slot)
-        else:
-            next_tokens = self._step_host(bucket, by_slot)
+            self._tokens_dev = out
+            self._tokens_bucket = exec_bucket
+            self._tokens_dirty = False
+        with span("engine.readback", cat="engine"):
+            next_tokens = self._read_ids(out)
         self.decode_steps += 1
-        if self.kv_layout == "paged":
-            pool.note_step()  # host mirror of the in-graph lengths + 1
-            for slot, req in by_slot.items():
-                tgt = self._fill_target.get(req.req_id)
-                if tgt is not None and pool.host_len[slot] >= tgt:
-                    # fill finished: publish the prompt's full blocks to the
-                    # radix tree for later requests to hit
-                    pool.commit_prefix(slot, req.prompt)
-                    del self._fill_target[req.req_id]
-            pairs = [(req, int(next_tokens[slot]))
-                     for slot, req in by_slot.items() if eligible[slot]]
-        else:
-            pairs = [(req, int(next_tokens[slot]))
-                     for slot, req in by_slot.items()]
-        self._finish_step(pairs)
+        with span("engine.finish", cat="engine"):
+            if paged:
+                pool.note_step()  # host mirror of the in-graph lengths + 1
+                for slot, req in by_slot.items():
+                    tgt = self._fill_target.get(req.req_id)
+                    if tgt is not None and pool.host_len[slot] >= tgt:
+                        # fill finished: publish the prompt's full blocks to
+                        # the radix tree for later requests to hit
+                        pool.commit_prefix(slot, req.prompt)
+                        del self._fill_target[req.req_id]
+                pairs = [(req, int(next_tokens[slot]))
+                         for slot, req in by_slot.items() if eligible[slot]]
+            else:
+                pairs = [(req, int(next_tokens[slot]))
+                         for slot, req in by_slot.items()]
+            self._finish_step(pairs)
         return n
 
     def _finish_step(self, pairs):

@@ -243,6 +243,61 @@ class TestTrace:
         assert validate_trace(doc) == []
         assert obs_trace.spans_named(doc, "disk")
 
+    def test_span_lands_in_the_profiler_trace(self, tmp_path):
+        """Under a profiler session a span is also a profiler event: its
+        name stays clean, its args (those given late too) arrive as stats,
+        and a child lies inside its parent's interval."""
+        from jax.profiler import ProfileData
+        with jax.profiler.trace(str(tmp_path)):
+            with span("outer.step", cat="t", rows=3) as sp:
+                with span("outer.child", cat="t"):
+                    jax.numpy.ones(4).block_until_ready()
+                sp.set(bucket=4)
+        assert not obs_trace.active()  # the JSON sink stayed off
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        found = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("outer."):
+                        found[e.name] = (e.start_ns, e.duration_ns,
+                                         dict(e.stats))
+        assert set(found) == {"outer.step", "outer.child"}
+        (p0, pd, stats), (c0, cd, _) = found["outer.step"], found["outer.child"]
+        assert stats == {"rows": 3, "bucket": 4}
+        assert p0 <= c0 and c0 + cd <= p0 + pd
+        assert sp.seconds * 1e9 <= pd
+
+    def test_set_adds_args_to_the_json_event(self):
+        """Args given late through ``set`` join those given at the start,
+        and an exception's name is added beside them."""
+        obs_trace.start()
+        with span("late", cat="t", rows=2) as sp:
+            sp.set(bucket=4)
+        with pytest.raises(KeyError):
+            with span("failed", cat="t") as sp:
+                sp.set(bucket=1)
+                raise KeyError("x")
+        doc = obs_trace.stop().to_dict()
+        (late,) = obs_trace.spans_named(doc, "late")
+        (failed,) = obs_trace.spans_named(doc, "failed")
+        assert late["args"] == {"rows": 2, "bucket": 4}
+        assert failed["args"] == {"bucket": 1, "error": "KeyError"}
+
+    def test_span_off_path_is_cheap(self):
+        """No profiler session and no tracing: a span is a timer and one
+        ``is_enabled()`` call. Best of many short batches, to ride out the
+        jitter of a loaded machine."""
+        def batch(n=500):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with span("cheap", cat="t"):
+                    pass
+            return (time.perf_counter() - t0) / n
+        batch(10)
+        per_op = min(batch() for _ in range(20))
+        assert per_op < 2e-6, f"span costs {per_op * 1e9:.0f}ns off"
+
 
 # ---------------------------------------------------------------------------
 # integration: the serving stack feeds the same numbers it reports
@@ -314,3 +369,63 @@ class TestServingIntegration:
         assert h.snapshot()[2] == len(reqs)
         tpot = obs_metrics.REGISTRY.get("serving_tpot_seconds")
         assert tpot.snapshot()[2] > 0, "no decode-step TPOT observed"
+
+    def test_step_spans_in_order_inside_the_parent(self, saved_archive):
+        """One step of a paged engine is one engine.step span over its six
+        children, in the order they run, on the same thread."""
+        eng = make_engine()
+        eng.cold_start_foundry(saved_archive)
+        eng.submit([5, 9, 2], 4)
+        obs_trace.start()
+        assert eng.step() == 1
+        doc = obs_trace.stop().to_dict()
+        assert validate_trace(doc) == []
+        (step,) = obs_trace.spans_named(doc, "engine.step")
+        # the bucket the program ran at: a template may pad-serve bucket 1
+        assert step["args"] == {"rows": 1, "bucket": eng.pool.cur_bucket,
+                                "fill_rows": 1}
+        kids = sorted((e for e in doc["traceEvents"] if e.get("ph") == "X"
+                       and e["name"].startswith("engine.")
+                       and e is not step), key=lambda e: e["ts"])
+        assert [e["name"] for e in kids] == [
+            "engine.admit", "engine.pool_sync", "engine.tokens",
+            "engine.dispatch", "engine.readback", "engine.finish"]
+        for e in kids:
+            assert e["tid"] == step["tid"]
+            assert step["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= step["ts"] + step["dur"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"]
+        # a tick with nothing queued or running records no step
+        eng.run_until_drained()
+        obs_trace.start()
+        assert eng.step() == 0
+        assert not obs_trace.spans_named(obs_trace.stop().to_dict(),
+                                         "engine.step")
+
+    def test_tpot_observes_the_step_span(self, saved_archive, monkeypatch):
+        """serving_tpot_seconds is fed the engine.step span's own seconds:
+        one measurement, two consumers."""
+        from repro.serving import engine as engine_mod
+        seen = []
+
+        class Recording(span):
+            __slots__ = ()
+
+            def __exit__(self, *exc):
+                out = super().__exit__(*exc)
+                if self.name == "engine.step":
+                    seen.append(self.seconds)
+                return out
+
+        monkeypatch.setattr(engine_mod, "span", Recording)
+        obs_metrics.enable()
+        eng = make_engine()
+        eng.cold_start_foundry(saved_archive)
+        eng.submit([5, 9, 2], 3)
+        eng.run_until_drained()
+        counts, total, n = obs_metrics.REGISTRY.get(
+            "serving_tpot_seconds").snapshot()
+        assert n == len(seen) == eng.decode_steps > 0
+        assert total == sum(seen)
+        assert obs_metrics.value("engine_decode_steps_total") == n
