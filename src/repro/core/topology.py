@@ -10,16 +10,19 @@ is concrete shapes. A topology key therefore hashes:
     deterministic topological order of the DAG),
   * dtypes and *ranks* (not sizes) of all operands/results,
   * structural params (dimension_numbers, scan structure, shardings,
-    shard_map specs, custom-call targets), recursing into sub-jaxprs,
+    shard_map specs, custom-call targets, a Pallas kernel's body and index
+    maps), recursing into sub-jaxprs,
 
-and excludes dimension sizes, so serve-step graphs for different batch-size
-buckets collapse to one key — unless batching changes the *program* (e.g. a
-bucket stops dividing the data axis and the sharding spec changes), which is
+and excludes dimension sizes (a Pallas kernel's grid among them: it is a
+launch dimension), so serve-step graphs for different batch-size buckets
+collapse to one key — unless batching changes the *program* (e.g. a bucket
+stops dividing the data axis and the sharding spec changes), which is
 precisely when the paper would also need a new template.
 """
 from __future__ import annotations
 
 import hashlib
+import re
 from functools import partial
 from typing import Any
 
@@ -65,7 +68,24 @@ def _norm_param(v: Any, h) -> None:
     if v is None:
         h.update(b"N")
         return
-    # partition specs, shardings, callables, avals: use stable str forms
+    if hasattr(v, "shape") and hasattr(v, "dtype"):  # an aval: rank only
+        _hash_aval(v, h)
+        return
+    if hasattr(v, "grid") and hasattr(v, "block_mappings"):
+        # a Pallas kernel's grid and block shapes are its launch dimensions:
+        # per-node parameters like any other size. Their ranks and the
+        # index maps are structure.
+        h.update(b"G")
+        h.update(bytes([len(v.grid)]))
+        for bm in v.block_mappings:
+            h.update(bytes([len(bm.block_shape)]))
+            _norm_param(bm.index_map_jaxpr, h)
+        return
+    if isinstance(v, jax.tree_util.PyTreeDef):
+        # a Pallas ref indexer carries the ref's shape in its tree: sizes
+        h.update(re.sub(r"\d+", "#", str(v)).encode())
+        return
+    # partition specs, shardings, callables: use stable str forms
     h.update(type(v).__name__.encode())
     try:
         h.update(str(v).encode())

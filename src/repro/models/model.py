@@ -22,8 +22,10 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeCell, SHAPE_CELLS
+from repro.kernels.decode_attention import decode_attention_paged_kernel
 from repro.launch.mesh import ShardCtx
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (
@@ -545,11 +547,11 @@ class Model:
                            lengths):
         """One-token attention against a per-layer paged pool. The new K/V
         scatters into each row's current write slot (block_tables[row,
-        length//bs], offset length%bs); attention gathers each row's blocks
-        into a dense [B, MB*bs] view and reuses the masked dense kernel —
-        padded rows point every table entry at the scratch block and their
-        garbage is masked by ``pos <= length`` before the softmax."""
-        c, ctx = self.cfg, self.ctx
+        length//bs], offset length%bs); attention reads each row's live
+        table blocks, up to ``lengths[row]``, through the paged kernel.
+        Padded rows point every table entry at the scratch block; their
+        output is never read."""
+        c = self.cfg
         B, D = x_t.shape
         H, Hkv, Dh = c.num_heads, c.num_kv_heads, c.head_dim
         NB, bs = k_pool.shape[0], k_pool.shape[1]  # per-layer [NB,bs,Hkv,Dh]
@@ -572,16 +574,29 @@ class Model:
         vf = vf.at[widx].set(v[:, 0].astype(vf.dtype))
         k_pool = kf.reshape(k_pool.shape)
         v_pool = vf.reshape(v_pool.shape)
-        # gather each row's table into a dense bshd view and mask-attend
-        gidx = ((block_tables * bs)[:, :, None]
-                + jnp.arange(bs)[None, None, :]).reshape(B, MB * bs)
-        kd, vd = kf[gidx], vf[gidx]
-        if self.kv_shard:
-            kd = ctx.constrain(kd, "batch", None, "kv_heads", None)
-            vd = ctx.constrain(vd, "batch", None, "kv_heads", None)
-        out = decode_attention_dense(q, kd, vd, lengths, layout="bshd")
+        out = self._paged_attention(q[:, 0], k_pool, v_pool, block_tables,
+                                    lengths)
         out = out.reshape(B, H * Dh) @ lw["wo"]
-        return ctx.constrain(out, "batch", None), k_pool, v_pool
+        return self.ctx.constrain(out, "batch", None), k_pool, v_pool
+
+    def _paged_attention(self, q, k_pool, v_pool, block_tables, lengths):
+        """q: [B, H, Dh] -> [B, H, Dh] over the live blocks of each row.
+        On a mesh the kernel runs per shard: rows split as the batch axis
+        does, kv heads (with their query groups) as the pool's head axis
+        does; every shard holds the whole table."""
+        ctx = self.ctx
+        if ctx.mesh is None:
+            return decode_attention_paged_kernel(q, k_pool, v_pool,
+                                                 block_tables, lengths)
+        rows = ctx.spec(("batch",), q.shape[:1])[0]
+        heads = ctx.spec(self.paged_cache_logical_axes()[1:],
+                         k_pool.shape)[2]
+        mapped = jax.shard_map(
+            decode_attention_paged_kernel, mesh=ctx.mesh,
+            in_specs=(P(rows, heads, None), P(None, None, heads, None),
+                      P(None, None, heads, None), P(rows, None), P(rows)),
+            out_specs=P(rows, heads, None), check_vma=False)
+        return mapped(q, k_pool, v_pool, block_tables, lengths)
 
     def decode_step_paged(self, params, cache, tokens):
         """Paged-layout decode step: same contract as ``decode_step`` but

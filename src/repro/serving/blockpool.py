@@ -492,6 +492,14 @@ class PagedKVCachePool:
         self.dirty = False
         return bt.nbytes + ln.nbytes
 
+    def attended_blocks(self) -> int:
+        """Blocks the captured step's attention reads for the active rows:
+        each row's table up to its write position, ``min(blocks_per_seq,
+        host_len // block_size + 1)``."""
+        bs, mb = self.block_size, self.blocks_per_seq
+        return sum(min(mb, n // bs + 1)
+                   for s, n in zip(self.slots, self.host_len) if s is not None)
+
     def note_step(self):
         """Mirror the captured step's ``lengths + 1`` on the host."""
         for i, s in enumerate(self.slots):

@@ -634,9 +634,12 @@ class ServingEngine:
         A step that has work is one ``engine.step`` span (args ``rows``,
         ``bucket``, ``fill_rows``) over six children in the order they run:
         ``engine.admit``, ``engine.pool_sync``, ``engine.tokens``,
-        ``engine.dispatch`` (an asynchronous enqueue), ``engine.readback``
-        (the wait for the device) and ``engine.finish``. The span's
-        ``seconds`` feed ``serving_tpot_seconds`` and
+        ``engine.dispatch`` (an asynchronous enqueue; on the paged pool
+        with args ``kv_live_blocks``, the blocks attention reads for the
+        active rows, and ``kv_table_blocks``, bucket x blocks per row),
+        ``engine.readback`` (the wait for the device) and
+        ``engine.finish``. The span's ``seconds`` feed
+        ``serving_tpot_seconds`` and
         ``engine_decode_steps_total``. A tick with nothing queued or
         running records no span; one that admits nothing from its queue
         records an ``engine.step`` with ``rows=0`` and only its
@@ -694,7 +697,11 @@ class ServingEngine:
             else:
                 # the pre-fusion loop re-packs the tokens every step
                 toks = self._rebuild_tokens(exec_bucket, by_slot)
-        with span("engine.dispatch", cat="engine"):
+        with span("engine.dispatch", cat="engine") as dsp:
+            if paged:
+                # the share of the whole table that attention reads
+                dsp.set(kv_live_blocks=pool.attended_blocks(),
+                        kv_table_blocks=exec_bucket * pool.blocks_per_seq)
             # donated cache; the fused program samples on the device
             pool.cache, out = exe(self.params, pool.cache, toks)
         if self.decode_loop == "device":

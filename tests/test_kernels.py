@@ -10,7 +10,8 @@ import pytest
 
 from repro.core.kernel_catalog import KernelCatalog
 from repro.kernels import ops, ref
-from repro.kernels.decode_attention import (decode_attention_kernel,
+from repro.kernels.decode_attention import (CHUNK_POSITIONS,
+                                            decode_attention_kernel,
                                             decode_attention_paged_kernel)
 from repro.kernels.moe_gemm import moe_grouped_gemm_kernel
 from repro.kernels.ssm_scan import mamba1_scan_kernel
@@ -77,23 +78,67 @@ class TestPagedDecodeAttention:
         tables = jnp.asarray(perm[:B * MB].reshape(B, MB), jnp.int32)
         return kp, vp, tables
 
-    @pytest.mark.parametrize("B,MB,bs,H,Hkv,Dh", [
-        (2, 4, 64, 8, 2, 64),
-        (1, 2, 256, 4, 4, 128),   # MHA
-        (3, 8, 16, 8, 1, 64),     # MQA, small blocks
-        (2, 4, 64, 16, 4, 128),
+    @pytest.mark.parametrize("B,MB,bs,H,Hkv,Dh,lengths", [
+        pytest.param(*case, "random", id="-".join(map(str, case)))
+        for case in [
+            (2, 4, 64, 8, 2, 64),
+            (1, 2, 256, 4, 4, 128),   # MHA
+            (3, 8, 16, 8, 1, 64),     # MQA, small blocks
+            (2, 4, 64, 16, 4, 128),
+        ]] + [
+        (8, 24, 16, 15, 5, 64, "edges"),    # smollm-360m widths, G = 3
+        (8, 24, 16, 32, 4, 128, "edges"),   # yi-9b widths, G = 8
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_matches_ref(self, B, MB, bs, H, Hkv, Dh, dtype):
+    def test_matches_ref(self, B, MB, bs, H, Hkv, Dh, lengths, dtype):
         ks = jax.random.split(jax.random.PRNGKey(10), 3)
         q = jax.random.normal(ks[0], (B, H, Dh), dtype)
         kp, vp, tables = self._rand_pool(ks[1], B, MB, bs, Hkv, Dh, dtype)
-        lengths = jax.random.randint(ks[2], (B,), 1, MB * bs - 1)
+        if lengths == "random":
+            lengths = jax.random.randint(ks[2], (B,), 1, MB * bs - 1)
+        else:
+            # 0, bs-1, bs, both sides of the first chunk edge, MB*bs-1; then
+            # two padded rows, whose every entry names the scratch block 0
+            # (an inactive row's table) and whose lengths run on in-graph
+            lengths = jnp.asarray([0, bs - 1, bs, CHUNK_POSITIONS - 1,
+                                   CHUNK_POSITIONS, MB * bs - 1, 0, 2 * bs + 5])
+            tables = tables.at[-2:].set(0)
         out = decode_attention_paged_kernel(q, kp, vp, tables, lengths)
         want = ref.decode_attention_paged_ref(q, kp, vp, tables, lengths)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(want, np.float32),
             **_tols(dtype))
+
+    @pytest.mark.parametrize("bs,H,Hkv,Dh", [
+        (16, 15, 5, 64),    # smollm-360m widths
+        (16, 32, 4, 128),   # yi-9b widths
+    ])
+    def test_blocks_past_the_live_ones_are_never_read(self, bs, H, Hkv, Dh):
+        """Every pool block no row attends (scratch block 0 included, the
+        tail entries of each table point there) is NaN. A read of any of
+        them would reach the output through V (probability 0 times NaN):
+        masking the scores is not enough, the blocks must not be copied."""
+        B, MB = 5, 24
+        ks = jax.random.split(jax.random.PRNGKey(14), 2)
+        q = jax.random.normal(ks[0], (B, H, Dh), jnp.float32)
+        kp, vp, tables = self._rand_pool(ks[1], B, MB, bs, Hkv, Dh,
+                                         jnp.float32)
+        lengths = np.asarray([0, bs - 1, CHUNK_POSITIONS,
+                              CHUNK_POSITIONS + 3 * bs, MB * bs - 1])
+        n_live = np.minimum(lengths // bs + 1, MB)
+        tables = np.array(tables)
+        for b in range(B):
+            tables[b, n_live[b]:] = 0
+        live = {int(x) for b in range(B) for x in tables[b, :n_live[b]]}
+        dead = np.asarray([i for i in range(kp.shape[0]) if i not in live])
+        assert 0 in dead and len(dead) > B
+        tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+        clean = decode_attention_paged_kernel(q, kp, vp, tables, lengths)
+        poisoned = decode_attention_paged_kernel(
+            q, kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan), tables,
+            lengths)
+        assert np.isfinite(np.asarray(poisoned)).all()
+        np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(clean))
 
     def test_matches_contiguous_kernel_on_gathered_cache(self):
         B, MB, bs, H, Hkv, Dh = 2, 4, 64, 8, 2, 64

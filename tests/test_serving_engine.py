@@ -1,12 +1,15 @@
 """Serving engine end-to-end on CPU: vanilla vs foundry vs eager cold starts
 produce identical tokens; continuous batching; failure re-queue."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.registry import get_arch
-from repro.core import wait_for_background
+from repro.core import Archive, wait_for_background
+from repro.obs import trace
 from repro.models.model import Model
 from repro.serving.engine import ServingEngine
 
@@ -162,3 +165,76 @@ def test_pool_shrink_during_release_keeps_slots_valid():
     assert eng.pool.slots[survivor.slot] == survivor.req_id
     eng.run_until_drained()
     assert survivor.state.value == "done" and len(survivor.generated) == 9
+
+
+def test_paged_kernel_exact_load_matches_vanilla(monkeypatch):
+    """The paged step with the attention kernel inside it is SAVEd and
+    exact-LOADed with no compile and serves the vanilla engine's tokens;
+    ``engine.dispatch`` reports the blocks attention reads, counted here
+    from the device tables and lengths each dispatch hands the program."""
+    eng1 = make_engine()
+    archive, _ = eng1.save_archive()
+    eng1.cold_start_vanilla()
+    ref = serve_tokens(eng1, PROMPTS)
+
+    eng2 = make_engine()
+    rep = eng2.cold_start_foundry(Archive.from_bytes(archive.to_bytes()),
+                                  background_exact=False)
+    assert rep.mode == "foundry" and rep.fallback_compiles == 0
+    assert eng2.kv_layout == "paged"
+    pool, seen, program = eng2.pool, [], eng2._program
+
+    def recording(bucket):
+        exec_bucket, exe = program(bucket)
+
+        def run(params, cache, toks):
+            bt = np.asarray(cache["block_tables"])
+            ln = np.asarray(cache["lengths"])
+            rows = [i for i, s in enumerate(pool.slots) if s is not None]
+            n = [min(bt.shape[1], ln[i] // pool.block_size + 1) for i in rows]
+            # an active row's attended blocks are allocated, not scratch
+            assert all(bt[i, :k].all() for i, k in zip(rows, n))
+            seen.append((sum(n), bt.size))
+            return exe(params, cache, toks)
+        return exec_bucket, run
+
+    monkeypatch.setattr(eng2, "_program", recording)
+    trace.start()
+    try:
+        out = serve_tokens(eng2, PROMPTS)
+    finally:
+        doc = trace.stop().to_dict()
+    assert out == ref, "exact-LOADed paged program diverged from vanilla"
+    got = [(ev["args"]["kv_live_blocks"], ev["args"]["kv_table_blocks"])
+           for ev in trace.spans_named(doc, "engine.dispatch")]
+    assert got == seen and len(got) == eng2.decode_steps
+    assert all(live <= table for live, table in got)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_decode_logits_match_slot_path(dtype):
+    """Cached decode over the paged pool (the kernel, two chunks a row)
+    against the slot layout's dense attention, step by step from rows at
+    different lengths. Tolerance: float32 scores and softmax on both
+    paths, summed in another order (online over chunks vs one softmax),
+    so float32 agrees to 1e-4; bfloat16 rounds K, V and every activation
+    on both paths, and the two round differently once the sums differ."""
+    cfg = dataclasses.replace(get_arch("smollm-360m").reduced(),
+                              param_dtype=dtype)
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(3))
+    B, S, bs = 3, 256, 16
+    start = jnp.asarray([0, 100, 120], jnp.int32)
+    slot = {**m.init_cache(B, S), "lengths": start}
+    paged = {**m.init_cache_paged(B, S, B * S // bs + 1, bs),
+             "lengths": start}
+    slot_step, paged_step = jax.jit(m.decode_step), jax.jit(m.decode_step_paged)
+    tol = 1e-4 if dtype == "float32" else 5e-2
+    toks = jax.random.randint(jax.random.PRNGKey(4), (24, B), 0,
+                              cfg.vocab_size)
+    for t in range(toks.shape[0]):  # rows 1 and 2 cross position 128
+        slot, want = slot_step(params, slot, toks[t])
+        paged, got = paged_step(params, paged, toks[t])
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)

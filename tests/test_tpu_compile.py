@@ -12,19 +12,23 @@ and under several test workers only the worker given this file loads it.
 The persistent compilation cache is off around the compiles (a compile for
 a described chip is written to it but cannot be read back without one).
 """
+import dataclasses
 import functools
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.configs.registry import get_arch
+from repro.kernels import decode_attention
 from repro.kernels.decode_attention import (decode_attention_kernel,
                                             decode_attention_paged_kernel)
 from repro.kernels.moe_gemm import moe_grouped_gemm_kernel
 from repro.kernels.ssm_scan import mamba1_scan_kernel
+from repro.launch.mesh import ShardCtx
 from repro.models.model import Model
 from repro.serving.engine import ServingEngine
 
@@ -61,19 +65,58 @@ def _compile(fn, args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def test_served_paged_decode_step_smollm_360m(one_chip):
-    """The captured decode step the server runs: smollm-360m at full depth
-    and published widths, bf16, max_batch 8, max_seq 1024, paged pool."""
-    eng = ServingEngine(Model(get_arch("smollm-360m")), max_batch=8,
-                        max_seq=1024, bucket_mode="pow2")
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Kernels inside a traced program lower for the chip: this process's
+    backend is the CPU, which would pick the interpreter."""
+    monkeypatch.setattr(decode_attention, "resolve_interpret",
+                        lambda interpret: False)
+
+
+def _compile_served_step(sharding, cfg, max_batch, max_seq):
+    """The captured decode step the server runs, at its largest bucket,
+    with the paged pool of ``max_batch`` full rows plus scratch."""
+    eng = ServingEngine(Model(cfg), max_batch=max_batch, max_seq=max_seq,
+                        bucket_mode="pow2")
     assert eng.kv_layout == "paged"
     args = jax.tree.map(
-        lambda sd: jax.ShapeDtypeStruct(sd.shape, sd.dtype, sharding=one_chip),
-        eng._decode_args(8))
+        lambda sd: jax.ShapeDtypeStruct(sd.shape, sd.dtype, sharding=sharding),
+        eng._decode_args(max_batch))
+    assert args[1]["k"].shape[1] == max_batch * max_seq // 16 + 1
     compiled = jax.jit(eng._decode_fn(), donate_argnums=(1,)).lower(
         *args).compile()
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+    # the paged attention kernel, lowered by Mosaic inside the layer scan
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_served_paged_decode_step_smollm_360m(one_chip, mosaic):
+    """smollm-360m at full depth and published widths, bf16, the chat
+    replica's 32 rows x 2048 positions (4097 pool blocks)."""
+    _compile_served_step(one_chip, get_arch("smollm-360m"), 32, 2048)
+
+
+def test_served_paged_decode_step_yi_9b_l24(one_chip, mosaic):
+    """yi-9b's widths at 24 of its 48 layers, bf16, 16 rows x 4096
+    positions (4097 pool blocks)."""
+    cfg = dataclasses.replace(get_arch("yi-9b"), num_layers=24)
+    _compile_served_step(one_chip, cfg, 16, 4096)
+
+
+def test_served_paged_decode_step_kv_sharded(topo, mosaic):
+    """On TP=4 over the described 2x2 host, yi-9b's 4 kv heads shard the
+    pool: the kernel runs per shard under shard_map (4 of its layers)."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = dataclasses.replace(get_arch("yi-9b"), num_layers=4)
+    model = Model(cfg, ShardCtx(mesh=mesh))
+    assert model.kv_shard
+    eng = ServingEngine(model, max_batch=16, max_seq=4096, bucket_mode="pow2")
+    assert eng.kv_layout == "paged"
+    compiled = jax.jit(eng._decode_fn(), donate_argnums=(1,)).lower(
+        *eng._decode_args(16)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("arch", ["smollm-360m", "qwen3-14b"])
