@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import spec as spec_mod
 from harness import weights
 
 
@@ -43,7 +42,7 @@ def gaps(cell, seed: int, reqs: list,
     of the tokens the reference at that precision puts first), and the
     number of tokens compared."""
     d = cell.dims
-    ref = spec_mod.reference_module(cell.config, cell.bench)
+    ref = d.family
     seqs = [list(r.prompt) + list(r.generated) for r in reqs]
     layer = weights.layer_fn(d, seed)
     top = weights.top_of(d, seed)

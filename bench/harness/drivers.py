@@ -51,6 +51,8 @@ class ScaleOut:
     fallback_compiles: int
     background_errors: int
     req: Any
+    probe_steps_s: List[float] = field(default_factory=list)  # to 1st token
+    parts_s: List[float] = field(default_factory=list)  # open, engine, LOAD
 
     @property
     def cold_s(self) -> float:
@@ -161,19 +163,24 @@ def coldstart(cell, d, params, archive, seed: int,
     def scale_out(g: traffic.Gen) -> ScaleOut:
         t0 = time.perf_counter()
         with TraceAnnotation("bench.load"):
-            eng, rep = system.cold_start(cell.config_name, d, params, archive)
+            parts = []
+            eng, rep = system.cold_start(cell.config_name, d, params,
+                                         archive, parts)
         t_loaded = time.perf_counter()
         with TraceAnnotation("bench.probe"):
             req = eng.submit(g.prompt, g.max_new)
+            steps = []
             while req.first_token_t is None and eng.scheduler.pending:
+                t = time.perf_counter()
                 eng.step()
+                steps.append(time.perf_counter() - t)
             eng.run_until_drained()
         first = req.first_token_t if req.first_token_t is not None \
             else float("nan")
         system.join_background(rep)
         out = ScaleOut(t0, t_loaded, first, rep.critical_path_s,
                        rep.restore_path, rep.fallback_compiles,
-                       rep.background_errors, req)
+                       rep.background_errors, req, steps, parts)
         del eng
         gc.collect()  # engine and pool refer to each other: free its HBM now
         return out

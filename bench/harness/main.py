@@ -23,7 +23,7 @@ from typing import Any, Dict, Optional
 from harness import check, drivers, spec, system
 from harness import trace as trace_mod
 from harness import weights
-from harness.spec import BENCH, Cell, Dims
+from harness.spec import BENCH, Cell
 
 
 class NoChip(RuntimeError):
@@ -34,7 +34,7 @@ class NoChip(RuntimeError):
 class Run:
     """What a metric's reader reads."""
     cell: Cell
-    dims: Dims
+    dims: Any  # the sizes of the cell's family
     peak: Optional[Dict[str, Any]]
     setup_s: float
     window: drivers.Window
@@ -174,6 +174,18 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, *,
             f"{sum(slow) / 1e3:.3f}s; outside steps "
             f"{window.seconds - sum(ms) / 1e3:.3f}s; {gcp.n} collections "
             f"{gcp.total * 1e3:.1f}ms (longest {gcp.longest * 1e3:.1f}ms)")
+    if window.scaleouts:
+        so = window.scaleouts
+        log("[scale-outs] cold_s " + " ".join(f"{s.cold_s:.3f}" for s in so))
+        log("[scale-outs] to LOAD's return s " + " ".join(
+            f"{s.t_loaded - s.t0:.3f}" for s in so))
+        log("[scale-outs] archive open / engine / LOAD ms " + " ".join(
+            "/".join(f"{x * 1e3:.0f}" for x in s.parts_s) for s in so))
+        log("[scale-outs] probe steps, slowest ms " + " ".join(
+            f"{len(s.probe_steps_s)}/{max(s.probe_steps_s, default=0) * 1e3:.1f}"
+            for s in so))
+        log(f"[stalls] {gcp.n} collections {gcp.total * 1e3:.1f}ms "
+            f"(longest {gcp.longest * 1e3:.1f}ms)")
     stats = dev.memory_stats() or {}
     mem_peak = int(stats.get("peak_bytes_in_use", 0))
 

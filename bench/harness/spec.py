@@ -2,19 +2,39 @@
 
     BENCHMARK.json                  cells, metrics and bounds (repo root)
     bench/configs/<config>.json     sizes as run, source, reduced, engine shape
-    bench/configs/<reference>.py    the plain reference a config names
+    bench/configs/<reference>.py    the family module a config names
     bench/traffic/<traffic>.json    parameters of one traffic mix
     bench/cells/<workload>.json     the cell's own parameters (rate, limits)
     bench/metrics/<metric>.py       one reader per metric: read(run) -> float|None
     bench/peaks.json                published peaks, keyed by device_kind
 
 Adding a cell, a mix, a configuration or a metric adds files and entries;
-no code here changes.
+no code here changes. A configuration of a new architecture brings its
+family module, and no harness file names one.
+
+A family module provides everything that depends on the architecture:
+
+    dims(config)                the family's sizes object (below)
+    program_config(name, d)     keyword arguments of the program's ArchConfig
+    top_shapes(d)               {leaf: shape} outside the layers, in draw order
+    layer_groups(d)             [(tree key, number of layers, {leaf: shape})],
+                                layers numbered globally across the groups
+    step_flops(d, rows, ctx)    work of one decode step of ``rows`` live rows
+    step_bytes(d, rows, ctx)    attending to ``ctx`` positions in all
+    logits(d, layer, top, seqs, quant=None)   the plain float32 reference
+
+The sizes object ``d`` carries ``family``, its module, and the basics that
+the generic harness (drivers, check, main, weights, readers) reads:
+``vocab``, ``padded_vocab``, ``dtype``, ``max_batch``, ``max_seq`` and
+``kv_block_size``. Everything else in it is the family's own.
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
+import re
+import sys
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List, Optional
@@ -22,49 +42,10 @@ from typing import Any, Dict, List, Optional
 BENCH = Path(__file__).resolve().parents[1]
 
 
-@dataclass(frozen=True)
-class Dims:
-    """The sizes of a dense GQA decoder as its configuration file states
-    them (Hugging Face ``config.json`` keys), plus the engine shape."""
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    tied: bool
-    rope_theta: float
-    norm_eps: float
-    dtype: str
-    max_batch: int
-    max_seq: int
-    kv_block_size: int
-
-    @property
-    def padded_vocab(self) -> int:
-        """Rows of the embedding table: the vocab rounded up to 256."""
-        return -(-self.vocab // 256) * 256
-
-    @property
-    def dtype_bytes(self) -> int:
-        return {"bfloat16": 2, "float16": 2, "float32": 4}[self.dtype]
-
-
-def dims(config: Dict[str, Any]) -> Dims:
-    """``Dims`` of a configuration file's contents."""
-    D, H = config["hidden_size"], config["num_attention_heads"]
-    eng = config["engine"]
-    return Dims(
-        layers=config["num_hidden_layers"], d_model=D, heads=H,
-        kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim") or D // H,
-        d_ff=config["intermediate_size"], vocab=config["vocab_size"],
-        tied=bool(config["tie_word_embeddings"]),
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        dtype=config["torch_dtype"], max_batch=eng["max_batch"],
-        max_seq=eng["max_seq"], kv_block_size=eng["kv_block_size"])
+def dims(config: Dict[str, Any], bench: Path = BENCH):
+    """The sizes of a configuration file's contents, as its family gives
+    them."""
+    return reference_module(config, bench).dims(config)
 
 
 @dataclass
@@ -82,8 +63,8 @@ class Cell:
     bench: Path = field(default=BENCH)
 
     @property
-    def dims(self) -> Dims:
-        return dims(self.config)
+    def dims(self):
+        return dims(self.config, self.bench)
 
     def metrics(self, trace: bool) -> List[Dict[str, Any]]:
         """The metrics this cell reports: end-to-end ones with ``trace``
@@ -123,10 +104,22 @@ def load_cell(workload: str, bench: Path = BENCH,
 
 
 def load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + name.replace(".", "_").replace("-", "_"), path)
+    """The module of ``path``, executed once per process. It is registered
+    under a name unique to its path, so that a family's sizes object can
+    find its module (``sys.modules``) and two bench trees never share one."""
+    path = Path(path).resolve()
+    tag = hashlib.sha1(str(path).encode()).hexdigest()[:12]
+    key = "bench_" + re.sub(r"\W", "_", name) + "_" + tag
+    if key in sys.modules:
+        return sys.modules[key]
+    spec = importlib.util.spec_from_file_location(key, path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[key] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[key]
+        raise
     return mod
 
 
@@ -136,7 +129,8 @@ def metric_reader(name: str, bench: Path = BENCH):
 
 
 def reference_module(config: Dict[str, Any], bench: Path = BENCH):
-    """The plain reference the configuration names (``"reference"``)."""
+    """The family module the configuration names (``"reference"``): its
+    plain reference and everything else that depends on the architecture."""
     name = config["reference"]
     return load_module(bench / "configs" / f"{name}.py", name)
 

@@ -1,18 +1,25 @@
 """The system under test, as the benchmark drives it.
 
 This is the only module that imports the program (``src/repro``). It builds
-the program's ``ArchConfig`` from a configuration file's sizes, hands the
-benchmark's weights to a ``ServingEngine``, SAVEs the decode capture set
-and cold-starts engines from it by foundry LOAD.
+the program's ``ArchConfig`` from the keyword arguments that the
+configuration's family module gives, hands the benchmark's weights to a
+``ServingEngine`` after checking them against the program's parameter tree,
+SAVEs the decode capture set and cold-starts engines from it by foundry
+LOAD.
+
+A family module (``d.family``; ``harness.spec`` states the contract)
+provides ``dims``, ``program_config``, ``top_shapes``, ``layer_groups``,
+``step_flops``, ``step_bytes`` and the reference ``logits``. This module
+uses ``program_config(name, d)`` alone, and of the sizes ``d`` only
+``max_batch``, ``max_seq`` and ``kv_block_size``.
 """
 from __future__ import annotations
 
 import gc
 import os
 import sys
+import time
 from pathlib import Path
-
-from harness.spec import Dims
 
 
 def use_program(root: Path):
@@ -38,16 +45,12 @@ def configure_cache(root: Path) -> tuple:
     return configure_compile_cache()
 
 
-def arch_config(name: str, d: Dims):
+def arch_config(name: str, d):
     from repro.configs.base import ArchConfig
-    return ArchConfig(
-        name=name, family="dense", num_layers=d.layers, d_model=d.d_model,
-        num_heads=d.heads, num_kv_heads=d.kv_heads, head_dim=d.head_dim,
-        d_ff=d.d_ff, vocab_size=d.vocab, tie_embeddings=d.tied,
-        rope_theta=d.rope_theta, norm_eps=d.norm_eps, param_dtype=d.dtype)
+    return ArchConfig(**d.family.program_config(name, d))
 
 
-def engine(name: str, d: Dims, params):
+def engine(name: str, d, params):
     """A fresh engine of the configuration's shape over ``params``."""
     import jax
     from repro.models.model import Model
@@ -65,7 +68,7 @@ def engine(name: str, d: Dims, params):
     return eng
 
 
-def save(name: str, d: Dims, params, path: Path) -> dict:
+def save(name: str, d, params, path: Path) -> dict:
     """SAVE the decode capture set of the configuration's engine. The
     engine's KV pool is freed before this returns."""
     eng = engine(name, d, params)
@@ -75,13 +78,19 @@ def save(name: str, d: Dims, params, path: Path) -> dict:
     return rep
 
 
-def cold_start(name: str, d: Dims, params, path: Path):
+def cold_start(name: str, d, params, path: Path, times=None):
     """Open the archive as a new object, build a new engine and LOAD it.
-    Returns the engine and its ``LoadReport``."""
+    Returns the engine and its ``LoadReport``; appends the host seconds
+    of the three parts to ``times`` when it is given."""
     from repro.core import Archive
+    t0 = time.perf_counter()
     archive = Archive.load(str(path))
+    t1 = time.perf_counter()
     eng = engine(name, d, params)
+    t2 = time.perf_counter()
     eng.cold_start_foundry(archive)
+    if times is not None:
+        times.extend((t1 - t0, t2 - t1, time.perf_counter() - t2))
     return eng, eng._load_report
 
 

@@ -1,39 +1,30 @@
-"""Seeded weights of a dense GQA decoder, made by the benchmark.
+"""Seeded weights of a configuration, made by the benchmark.
 
-Every leaf of layer ``l`` depends on the seed and ``l`` alone, so the plain
-reference remakes one layer at a time after the program's state is freed,
-bit for bit the values the program was given. The program receives the
-whole tree, made on the device in one jitted call in the served dtype.
+The family module declares the tree: leaves outside the layers
+(``top_shapes``) and an ordered list of layer groups (``layer_groups``),
+each ``(tree key, number of layers, {leaf: shape})``, with layers numbered
+globally across the groups. Layer ``l`` is drawn from ``fold_in(key, l)``
+and its leaf ``i`` from ``fold_in(., i)`` in declaration order; the top
+leaves from ``fold_in(key, _TOP)``. So every leaf of a layer depends on the
+seed and its global index alone, and the plain reference remakes one layer
+at a time after the program's state is freed, bit for bit the values the
+program was given. The program receives the whole tree, made on the device
+in one jitted call in the served dtype, each group stacked ``[n, ...]``
+under its key.
 
-Matrices are N(0, 0.02) (``initializer_range`` of both configurations'
-``config.json``); norm scales are 1 + N(0, 0.1), so a path that ignores a
-norm's scale disagrees with the reference.
+Matrices are N(0, 0.02) (``initializer_range`` of the configurations'
+``config.json``); norm scales (a leaf named ``ln*`` or ``*norm``) are
+1 + N(0, 0.1), so a path that ignores a norm's scale disagrees with the
+reference.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from harness.spec import Dims
-
 INIT_STD = 0.02
 NORM_STD = 0.1
 _TOP = 0xFFFFFFF0  # fold-in index of the non-layer leaves
-
-
-def layer_shapes(d: Dims) -> dict:
-    D, F = d.d_model, d.d_ff
-    q, kv = d.heads * d.head_dim, d.kv_heads * d.head_dim
-    return {"ln_attn": (D,), "wq": (D, q), "wk": (D, kv), "wv": (D, kv),
-            "wo": (q, D), "ln_mlp": (D,), "w_gate": (D, F), "w_up": (D, F),
-            "w_down": (F, D)}
-
-
-def top_shapes(d: Dims) -> dict:
-    out = {"embed": (d.padded_vocab, d.d_model), "final_norm": (d.d_model,)}
-    if not d.tied:
-        out["lm_head"] = (d.d_model, d.padded_vocab)
-    return out
 
 
 def seed_key(seed: int):
@@ -52,34 +43,58 @@ def _leaves(key, shapes: dict, dtype) -> dict:
     return out
 
 
-def layer(d: Dims, key, index) -> dict:
+def _groups(d) -> list:
+    """``(tree key, first global layer, number of layers, leaf shapes)`` of
+    each of the family's layer groups, in order."""
+    out, first = [], 0
+    for name, n, shapes in d.family.layer_groups(d):
+        out.append((name, first, n, shapes))
+        first += n
+    return out
+
+
+def _layer(key, index, shapes: dict, dtype) -> dict:
     """Leaves of layer ``index`` (a traced or a Python int)."""
-    return _leaves(jax.random.fold_in(key, index), layer_shapes(d),
+    return _leaves(jax.random.fold_in(key, index), shapes, dtype)
+
+
+def top(d, key) -> dict:
+    return _leaves(jax.random.fold_in(key, _TOP), d.family.top_shapes(d),
                    jnp.dtype(d.dtype))
 
 
-def top(d: Dims, key) -> dict:
-    return _leaves(jax.random.fold_in(key, _TOP), top_shapes(d),
-                   jnp.dtype(d.dtype))
+def make(d, seed: int) -> dict:
+    """The whole tree ``{group: {leaf: [n, ...]}, **top}``, made on the
+    default device in one call; a group's layers are made one after another
+    so that no more than one layer's float32 draw is alive at a time."""
+    dtype = jnp.dtype(d.dtype)
 
-
-def make(d: Dims, seed: int) -> dict:
-    """The whole tree ``{"layers": {leaf: [L, ...]}, **top}``, made on the
-    default device in one call; layers are made one after another so that
-    no more than one layer's float32 draw is alive at a time."""
     def build(key):
-        layers = jax.lax.map(lambda i: layer(d, key, i),
-                             jnp.arange(d.layers, dtype=jnp.uint32))
-        return {"layers": layers, **top(d, key)}
+        tree = {}
+        for name, first, n, shapes in _groups(d):
+            tree[name] = jax.lax.map(
+                lambda i, shapes=shapes: _layer(key, i, shapes, dtype),
+                jnp.arange(first, first + n, dtype=jnp.uint32))
+        return {**tree, **top(d, key)}
     return jax.jit(build)(seed_key(seed))
 
 
-def layer_fn(d: Dims, seed: int):
-    """``f(index) -> leaves`` of one layer, jitted once for every index."""
+def layer_fn(d, seed: int):
+    """``f(index) -> leaves`` of one layer, whichever group holds it;
+    jitted once for each group."""
     key = seed_key(seed)
-    fn = jax.jit(lambda k, i: layer(d, k, i))
-    return lambda index: fn(key, jnp.uint32(index))
+    dtype = jnp.dtype(d.dtype)
+    fns = [(first, n, jax.jit(lambda k, i, shapes=shapes:
+                              _layer(k, i, shapes, dtype)))
+           for _, first, n, shapes in _groups(d)]
+
+    def layer(index: int) -> dict:
+        for first, n, fn in fns:
+            if first <= index < first + n:
+                return fn(key, jnp.uint32(index))
+        raise IndexError(f"no layer {index}")
+    return layer
 
 
-def top_of(d: Dims, seed: int) -> dict:
+def top_of(d, seed: int) -> dict:
     return jax.jit(lambda k: top(d, k))(seed_key(seed))

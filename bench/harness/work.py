@@ -8,51 +8,45 @@ low here by construction, and one that stops doing so reads higher.
 
 ``rows`` is the number of live rows of the step and ``ctx`` the sum over
 them of the positions each attends to, the new one included.
+
+The counts belong to the configuration's family module (``step_flops``,
+``step_bytes``), kept with the benchmark; this module reaches them through
+the sizes' ``family``. A family with routed experts counts, in bytes, the
+weights of each expert held on this chip and reached by some row of the
+step once, and, in FLOPs, ``top_k`` experts for every row; a family whose
+cache holds a latent counts the latent's values a position, not per-head
+K and V.
 """
 from __future__ import annotations
 
 from typing import Dict, Tuple
 
-from harness.spec import Dims
+
+def step_flops(d, rows: int, ctx: int) -> int:
+    return d.family.step_flops(d, rows, ctx)
 
 
-def matmul_params(d: Dims) -> int:
-    """Weights one row multiplies by: every layer's projections and the
-    output head (the embedding lookup is a gather, not a product)."""
-    D, F = d.d_model, d.d_ff
-    q, kv = d.heads * d.head_dim, d.kv_heads * d.head_dim
-    per_layer = D * q + 2 * D * kv + q * D + 3 * D * F
-    return d.layers * per_layer + D * d.vocab
+def step_bytes(d, rows: int, ctx: int) -> int:
+    return d.family.step_bytes(d, rows, ctx)
 
 
-def param_bytes(d: Dims) -> int:
-    """Bytes of weights one step reads: every layer, the final norm and the
-    output head. With an untied head the embedding table is read only at
-    the rows' tokens, which ``step_bytes`` adds."""
-    D = d.d_model
-    per_layer = matmul_params(d) - D * d.vocab
-    norms = d.layers * 2 * D + D
-    return (per_layer + norms + D * d.vocab) * d.dtype_bytes
+# The dense family's helpers, kept importable here only because
+# test_bench_units.py calls them. They are not part of the family contract
+# (spec.py): a family that does not define them raises AttributeError here,
+# and nothing in the harness calls them.
+def matmul_params(d) -> int:
+    return d.family.matmul_params(d)
 
 
-def kv_bytes_per_position(d: Dims) -> int:
-    return d.layers * 2 * d.kv_heads * d.head_dim * d.dtype_bytes
+def param_bytes(d) -> int:
+    return d.family.param_bytes(d)
 
 
-def step_flops(d: Dims, rows: int, ctx: int) -> int:
-    """2 x weights per row, plus QK^T and PV over the live positions."""
-    attn = 4 * d.layers * d.heads * d.head_dim * ctx
-    return 2 * rows * matmul_params(d) + attn
+def kv_bytes_per_position(d) -> int:
+    return d.family.kv_bytes_per_position(d)
 
 
-def step_bytes(d: Dims, rows: int, ctx: int) -> int:
-    """Weights once, the K/V of the live positions (the new position is
-    written and read), and the embedding rows of an untied table."""
-    gather = 0 if d.tied else rows * d.d_model * d.dtype_bytes
-    return param_bytes(d) + kv_bytes_per_position(d) * ctx + gather
-
-
-def least_time(d: Dims, peak: Dict, rows: int, ctx: int) -> Tuple[float, str]:
+def least_time(d, peak: Dict, rows: int, ctx: int) -> Tuple[float, str]:
     """Seconds the chip needs at least, and which bound sets it."""
     t_flops = step_flops(d, rows, ctx) / peak["bf16_flops_per_s"]
     t_bytes = step_bytes(d, rows, ctx) / peak["hbm_bytes_per_s"]

@@ -1,10 +1,10 @@
 """A bench tree in a temporary directory with a cell of its own, for tests.
 
 The tree holds only data and readers: a tiny dense GQA configuration
-(d_model 256, 2 layers, vocab 4096) beside the real reference, tiny batch
-and coldstart mixes and cells, copies of the real metric readers and peaks,
-and a ``BENCHMARK.json`` that names the tiny cells alongside the real
-metrics. The harness code that runs it is the
+(d_model 256, 2 layers, vocab 4096) beside a copy of the real family
+module, tiny batch and coldstart mixes and cells, copies of the real metric
+readers and peaks, and a ``BENCHMARK.json`` that names the tiny cells
+alongside the real metrics. The harness code that runs it is the
 repository's own, unedited.
 """
 from __future__ import annotations
@@ -47,15 +47,18 @@ def _write(path: Path, obj):
     path.write_text(json.dumps(obj, indent=1))
 
 
-def make(tmp: Path) -> Path:
-    """Build the tree under ``tmp``; returns its bench root."""
+def make(tmp: Path, family: str = "dense_gqa") -> Path:
+    """Build the tree under ``tmp``; returns its bench root. The tiny
+    configuration names the family module ``family``, a copy of
+    ``dense_gqa.py``: under another name it exists in this tree alone."""
     b = tmp / "bench"
     shutil.copytree(BENCH / "metrics", b / "metrics",
                     ignore=shutil.ignore_patterns("__pycache__"))
     (b / "configs").mkdir()
-    shutil.copy(BENCH / "configs" / "dense_gqa.py", b / "configs")
+    shutil.copy(BENCH / "configs" / "dense_gqa.py",
+                b / "configs" / f"{family}.py")
     shutil.copy(BENCH / "peaks.json", b)
-    _write(b / "configs" / "tiny.json", TINY)
+    _write(b / "configs" / "tiny.json", {**TINY, "reference": family})
     for name, mix in MIXES.items():
         _write(b / "traffic" / f"{name}.json", mix)
     for name in CELLS:
